@@ -40,9 +40,10 @@ degrades to a recompile instead of an exception — and commits
 ``REPRO_STORE_DIR`` to persist the store across invocations (the CI lane
 does, via ``actions/cache``).
 
-``--async`` runs the continuous-batching lane on >= 2 forced host devices
-(the process re-execs itself with ``--xla_force_host_platform_device_count``
-when it finds only one): an :class:`~repro.runtime.scheduler.AsyncEngine`
+``--async`` runs the continuous-batching lane over the devices there are;
+on the CPU backend with a single device the process re-execs itself with
+``--xla_force_host_platform_device_count`` to get a mesh (on a TPU it runs
+in-process, since only one process may hold a chip): an :class:`~repro.runtime.scheduler.AsyncEngine`
 serves the stream through per-bucket batching windows placed over the
 device mesh, against the synchronous per-arrival front-end it replaces
 (one ``submit([req])`` per arrival — what a sync engine actually does
@@ -648,7 +649,8 @@ ASYNC_PACE_S = 0.004
 def _reexec_async(smoke: bool) -> list:
     """Re-run this lane in a subprocess with forced host devices (the
     XLA device count is fixed at backend init, so an already-initialized
-    single-device process cannot grow a mesh in place)."""
+    single-device process cannot grow a mesh in place).  CPU only: a
+    child could not open a chip this process already holds."""
     import subprocess
     import sys
 
@@ -687,7 +689,7 @@ def run_async(smoke: bool = False):
     """
     from repro.runtime import AsyncEngine
 
-    if jax.device_count() < 2:
+    if jax.default_backend() == "cpu" and jax.device_count() < 2:
         return _reexec_async(smoke)
 
     from repro.graphs.batching import TrafficProfile

@@ -51,6 +51,7 @@ from .core.simulator import (
 from .gnn.layers import LAYER_FNS, EllAdjacency, init_layer
 from .gnn.model import GNNConfig, forward_layers, masked_xent_loss
 from .graphs.csr import CSRGraph
+from .kernels.common import resolve_use_pallas
 
 #: Artifact schema version.  Bump the suffix whenever the JSON layout of
 #: :meth:`Program.to_json` changes incompatibly (new required field,
@@ -292,6 +293,32 @@ class Program:
         same-shape input (including a same-shape :meth:`bind`) performs
         zero re-tracing (see :func:`repro.api.trace_count`).
         """
+        exe, args = self._call(
+            params, x, mesh, segment_ids, num_segments, readout, donate
+        )
+        return exe(*args)
+
+    def lowered(
+        self,
+        params,
+        x: jax.Array,
+        mesh=None,
+        *,
+        segment_ids=None,
+        num_segments: int | None = None,
+        readout: str | None = None,
+        donate: bool = False,
+    ) -> "jax.stages.Lowered":
+        """The executable :meth:`run` would call with these arguments, as
+        lowered by ``jax.jit`` (``.compile().as_text()`` shows which
+        kernels it runs)."""
+        exe, args = self._call(
+            params, x, mesh, segment_ids, num_segments, readout, donate
+        )
+        return exe.lower(*args)
+
+    def _call(self, params, x, mesh, segment_ids, num_segments, readout, donate):
+        """The shape-keyed executable and its arguments for one run."""
         adj = self._require_adj()
         if len(params) != self.n_layers:
             raise ValueError(
@@ -314,7 +341,7 @@ class Program:
         )
         if not batched:
             segment_ids = jnp.zeros(0, dtype=jnp.int32)  # unused placeholder
-        return exe(
+        return exe, (
             params, adj.indices, adj.weights, x, jnp.asarray(segment_ids)
         )
 
@@ -613,6 +640,10 @@ def compile(
     Returns a frozen :class:`Program`; with ``graph`` given, the program is
     already bound and ``program.run(params, x)`` executes immediately.
 
+    ``use_pallas`` picks the Pallas kernels over the jnp paths; ``None``
+    takes the config's flag, else uses Pallas exactly when JAX's default
+    backend is the TPU.
+
     ``latency_model`` installs a fitted :class:`LatencyModel` (see
     :mod:`repro.core.calibrate`) into the pricing config before any search
     or re-pricing runs, so candidate ranking uses calibrated cycles.  When
@@ -637,8 +668,9 @@ def compile(
     workloads, cfg = _resolve_workloads(target, graph)
     if kind is None:
         kind = cfg.kind if cfg is not None else "gcn"
-    if use_pallas is None:
-        use_pallas = cfg.use_pallas if cfg is not None else False
+    if use_pallas is None and cfg is not None:
+        use_pallas = cfg.use_pallas
+    use_pallas = resolve_use_pallas(use_pallas)
 
     if schedule is not None:
         want = [(wl.f_in, wl.g_out) for wl in workloads]

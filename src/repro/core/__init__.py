@@ -20,6 +20,7 @@ from .registry import (
     objective_value,
     register_kernel,
     register_objective,
+    resolve_kernel_key,
     unregister_objective,
 )
 from .cost_model import (

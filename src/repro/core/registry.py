@@ -175,22 +175,18 @@ def kernel_policies() -> tuple[str, ...]:
     return tuple(sorted({k[0] for k in _KERNELS}))
 
 
-def lookup_kernel(policy: str, order: str, use_pallas: bool = False) -> Callable:
-    """Resolve the executable path for an ``ExecSpec``.
-
-    A missing Pallas variant falls back to the jnp path of the same
-    ``(policy, order)`` — e.g. ``sp_generic`` has no Pallas kernel, and
-    ``sp_opt``'s fused kernel only covers the AC order.  Installed
-    dispatch hooks (:func:`push_kernel_hook`) wrap the resolved kernel,
-    keyed by the *requested* tuple.
-    """
+def resolve_kernel_key(
+    policy: str, order: str, use_pallas: bool = False
+) -> tuple[str, str, bool]:
+    """The registered ``(policy, order, use_pallas)`` key that serves a
+    request: the requested key, or the jnp path of the same
+    ``(policy, order)`` when it has no Pallas variant — e.g.
+    ``sp_generic`` has no Pallas kernel, and ``sp_opt``'s fused kernel only
+    covers the AC order."""
     requested = (policy, order, bool(use_pallas))
     for key in (requested, (policy, order, False)):
-        impl = _KERNELS.get(key)
-        if impl is not None:
-            for hook in _KERNEL_HOOKS:
-                impl = hook(requested, impl)
-            return impl
+        if key in _KERNELS:
+            return key
     if policy not in kernel_policies():
         raise ValueError(
             f"policy must be one of {kernel_policies()}, got {policy!r}"
@@ -198,3 +194,15 @@ def lookup_kernel(policy: str, order: str, use_pallas: bool = False) -> Callable
     raise ValueError(
         f"order must be one of {ORDERS}, got {order!r} (policy {policy!r})"
     )
+
+
+def lookup_kernel(policy: str, order: str, use_pallas: bool = False) -> Callable:
+    """Resolve the executable path for an ``ExecSpec`` (see
+    :func:`resolve_kernel_key` for the Pallas -> jnp resolution).
+    Installed dispatch hooks (:func:`push_kernel_hook`) wrap the resolved
+    kernel, keyed by the *requested* tuple."""
+    requested = (policy, order, bool(use_pallas))
+    impl = _KERNELS[resolve_kernel_key(*requested)]
+    for hook in _KERNEL_HOOKS:
+        impl = hook(requested, impl)
+    return impl

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..core.schedule import ModelSchedule
 from ..graphs.csr import CSRGraph
+from ..kernels.common import resolve_use_pallas
 from .layers import LAYER_FNS, EllAdjacency, init_layer, segment_readout
 
 #: set True after the first string-policy shim warning (reset by tests).
@@ -56,7 +57,7 @@ class GNNConfig:
     policy: str = "sp_opt"  # deprecated shim; see module docstring
     order: str = "AC"  # phase order
     band_size: int = 128
-    use_pallas: bool = False  # route kernels through Pallas when lowering
+    use_pallas: bool | None = None  # Pallas kernels; None = on the TPU
 
     @property
     def dims(self) -> list[tuple[int, int]]:
@@ -139,7 +140,8 @@ def gnn_forward(
         )
     return forward_layers(
         cfg.kind, params, adj, x,
-        schedule.lower(use_pallas=cfg.use_pallas), mesh=mesh,
+        schedule.lower(use_pallas=resolve_use_pallas(cfg.use_pallas)),
+        mesh=mesh,
     )  # logits (V, n_classes)
 
 

@@ -17,14 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map to the top level (check_vma arg)
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:  # jax 0.4/0.5: experimental home, check_rep arg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def mesh_devices(
     mesh: jax.sharding.Mesh | None = None,
@@ -114,11 +106,11 @@ def pp_multiphase_matmul(
         outs = jax.lax.psum(outs, phase_axis)
         return outs.reshape(n_bands * band_size, g_out)
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(P(), P(), P(), P()),
         out_specs=P(),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return shard(idx, wts, x, w)[: adj.n_nodes]

@@ -6,16 +6,68 @@ import time
 import jax
 import numpy as np
 
+#: the TPU's (sublane, lane) tile: a block's last two dimensions must be
+#: multiples of these, or the whole array dimension.
+SUBLANE, LANE = 8, 128
+
+#: bytes of one buffer of a kernel's resident (rows, block_f) vertex-table
+#: block.  Pallas double-buffers it, which leaves most of a TPU v5e's
+#: 16 MiB of scoped VMEM for the output and scratch blocks.
+TABLE_BLOCK_BYTES = 2 * 2**20
+
+#: SMEM bytes for one row block's neighbour indices and weights (two
+#: int32/f32 arrays, double-buffered, minor dimension padded to the lane
+#: width); the chip has 1 MiB of SMEM.
+SMEM_BLOCK_BYTES = 512 * 2**10
+
 
 def default_interpret() -> bool:
-    """Pallas kernels target TPU; on CPU hosts we validate with the
-    interpreter (assignment: interpret=True executes the kernel body in
-    Python for correctness)."""
-    return jax.default_backend() != "tpu"
+    """Whether Pallas kernels run in the interpreter: on the CPU, where
+    tests check the kernel bodies, and never on the TPU they target.  Any
+    other backend is refused rather than silently interpreted."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels target the TPU (interpreted on the CPU); "
+        f"backend {backend!r} is neither"
+    )
+
+
+def resolve_use_pallas(use_pallas: bool | None) -> bool:
+    """An explicit flag as given; ``None`` means Pallas exactly when JAX's
+    default backend is the TPU the kernels are written for."""
+    if use_pallas is None:
+        return jax.default_backend() == "tpu"
+    return bool(use_pallas)
 
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def lane_block_f(block_f: int | None, f: int, rows: int, itemsize: int = 4) -> int:
+    """The feature block a kernel can tile ``f`` columns with on the TPU.
+
+    The schedule's ``block_f`` (``None`` = as wide as possible) is rounded
+    up to whole 128-lane tiles and capped so one ``(rows, block)`` table
+    buffer fits :data:`TABLE_BLOCK_BYTES`; a block that would cover ``f``
+    becomes ``f`` itself (a full dimension is always legal).
+    """
+    cap = max(LANE, TABLE_BLOCK_BYTES // (rows * itemsize) // LANE * LANE)
+    bf = min(cdiv(min(block_f or f, f), LANE) * LANE, cap)
+    return f if bf >= f else bf
+
+
+def row_block(block_v: int, v: int, d: int) -> int:
+    """Rows per grid step: ``block_v`` rounded up to whole sublane tiles
+    and capped so the ``(rows, d)`` index and weight blocks fit
+    :data:`SMEM_BLOCK_BYTES`; a block that would cover ``v`` becomes ``v``."""
+    cap = SMEM_BLOCK_BYTES // (4 * 4 * cdiv(d, LANE) * LANE)
+    bv = min(cdiv(block_v, SUBLANE) * SUBLANE, max(SUBLANE, cap // SUBLANE * SUBLANE))
+    return v if bv >= v else bv
 
 
 def measure_wall(

@@ -5,42 +5,45 @@ the aggregated tile is kept *in the PEs* and consumed directly by the
 combination phase — ``SP_AC({V_x F_x} N_t, {V_x F_x} G_t)`` with
 T_V/T_F shared between phases and temporal reduction (T_N = 1).
 
-TPU translation: one ``pallas_call`` whose grid walks row blocks (T_V).
-Each step (a) gathers + accumulates the neighbor rows into a VMEM register
-tile h (the aggregation), then (b) immediately feeds h into the MXU matmul
-with the weight block (the combination).  The V x F intermediate never
-exists in HBM — that is the entire point of SP-Optimized, and it is the
-same trick flash-attention plays on the attention GEMM-GEMM chain.
+TPU translation: one ``pallas_call`` whose grid walks row blocks (T_V)
+and, innermost, feature blocks (T_F).  Each step (a) gathers + accumulates
+the neighbor rows of one feature block into a VMEM scratch tile h (the
+aggregation, see :func:`repro.kernels.spmm.kernel.gather_rows`), then
+(b) immediately feeds h into the MXU matmul with the matching rows of the
+weight (the combination), adding into the row block's float32 output.
+The V x F intermediate never exists in HBM — that is the entire point of
+SP-Optimized, and it is the same trick flash-attention plays on the
+attention GEMM-GEMM chain.  Only one (V, T_F) feature block of the vertex
+table is resident at a time, so the kernel's VMEM does not grow with F.
 
-The feature dimension is walked in ``block_f`` chunks with a float32 VMEM
-accumulator for the output — the paper's partial-sum overhead appears here
-as the accumulator revisits (kept on-chip because T_G = G fits VMEM).
+The price of the row-block-outer grid: with more than one feature block,
+every row block streams the whole (V, F) table from HBM again, so one
+call reads (V_pad / T_V) x V x F elements of it (the ELL SpMM kernel,
+row blocks inner, reads the table once).  With a single feature block
+the block index never changes and the table is read once.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..spmm.kernel import gather_rows
 
 
-def _kernel(idx_ref, wts_ref, x_ref, w_ref, o_ref, *, ell_width: int):
-    """out[b, :] = (sum_d wts[b,d] * x[idx[b,d], :]) @ w — fused."""
+def _kernel(idx_ref, wts_ref, x_ref, w_ref, o_ref, h_ref):
+    """out[b, :] += (sum_d wts[b,d] * x[idx[b,d], fblock]) @ w[fblock] — fused."""
 
-    def agg_body(d, acc):
-        rows = idx_ref[:, d]
-        gathered = x_ref[rows, :]  # (B, F)
-        return acc + wts_ref[:, d][:, None] * gathered
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    b = idx_ref.shape[0]
-    f = x_ref.shape[1]
-    h = jax.lax.fori_loop(
-        0, ell_width, agg_body, jnp.zeros((b, f), jnp.float32)
-    )  # the intermediate tile — lives only in VMEM
-    o_ref[...] = jnp.dot(
-        h, w_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+    gather_rows(idx_ref, wts_ref, x_ref, h_ref)  # the tile lives only in VMEM
+    o_ref[...] += jnp.dot(
+        h_ref[...], w_ref[...].astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+    )
 
 
 def fused_agg_cmb_kernel(
@@ -49,27 +52,34 @@ def fused_agg_cmb_kernel(
     x: jax.Array,  # (V, F)
     w: jax.Array,  # (F, G)
     *,
-    block_v: int = 128,
-    interpret: bool = True,
+    block_v: int,
+    block_f: int,
+    interpret: bool,
 ) -> jax.Array:
-    """Fused (A @ X) @ W with the intermediate pinned in VMEM."""
+    """Fused (A @ X) @ W with the intermediate pinned in VMEM; float32
+    (V_pad, G).  ``block_v`` must divide V_pad and ``block_f`` must
+    divide F (see :mod:`repro.kernels.fused_agg_cmb.ops`)."""
     v_pad, d = indices.shape
     v, f = x.shape
     f2, g = w.shape
     assert f == f2
-    bv = min(block_v, v_pad)
-    grid = (pl.cdiv(v_pad, bv),)
-    kernel = functools.partial(_kernel, ell_width=d)
+    bv, bf = block_v, block_f
+    smem = pl.BlockSpec((bv, d), lambda i, k: (i, 0), memory_space=pltpu.SMEM)
     return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((v_pad, g), x.dtype),
-        grid=grid,
+        _kernel,
+        out_shape=jax.ShapeDtypeStruct((v_pad, g), jnp.float32),
+        grid=(v_pad // bv, f // bf),
         in_specs=[
-            pl.BlockSpec((bv, d), lambda i: (i, 0)),
-            pl.BlockSpec((bv, d), lambda i: (i, 0)),
-            pl.BlockSpec((v, f), lambda i: (0, 0)),  # vertex table resident
-            pl.BlockSpec((f, g), lambda i: (0, 0)),  # weights resident
+            smem,
+            smem,
+            pl.BlockSpec((v, bf), lambda i, k: (0, k)),  # one feature block
+            pl.BlockSpec((bf, g), lambda i, k: (k, 0)),
         ],
-        out_specs=pl.BlockSpec((bv, g), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bv, g), lambda i, k: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((bv, bf), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
+        name="fused_agg_cmb",
     )(indices, weights, x, w)

@@ -1,19 +1,30 @@
-"""Jitted wrapper for the ELL SpMM aggregation kernel."""
+"""Jitted wrapper for the ELL SpMM aggregation kernel.
+
+The schedule's ``block_v`` / ``block_f`` become legal TPU blocks here
+(:func:`~repro.kernels.common.row_block`,
+:func:`~repro.kernels.common.lane_block_f`), and the rows and feature
+columns are zero-padded to whole blocks.
+
+Pallas gives ``pallas_call`` no transpose rule, so reverse-mode
+differentiation (``Program.train_step``) takes the VJP of the jnp oracle
+:func:`~repro.kernels.spmm.ref.spmm_ref`; the forward pass stays on the
+kernel.
+"""
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import cdiv, default_interpret
+from ..common import cdiv, default_interpret, lane_block_f, row_block
 from .kernel import spmm_ell as _raw
+from .ref import spmm_ref
 
 
-@functools.partial(jax.jit, static_argnames=("block_v", "block_f"))
-def spmm(indices, weights, x, block_v=128, block_f=128):
+def _spmm_kernel(indices, weights, x, block_v, block_f):
     v_pad, d = indices.shape
     v, f = x.shape
-    bv, bf = min(block_v, v_pad), min(block_f, f)
+    bv, bf = row_block(block_v, v_pad, d), lane_block_f(block_f, f, v)
     vp = cdiv(v_pad, bv) * bv
     fp = cdiv(f, bf) * bf
     idx = jnp.pad(indices, ((0, vp - v_pad), (0, 0)))
@@ -22,6 +33,27 @@ def spmm(indices, weights, x, block_v=128, block_f=128):
     out = _raw(idx, wts, xp, block_v=bv, block_f=bf,
                interpret=default_interpret())
     return out[:v_pad, :f]
+
+
+_spmm = jax.custom_vjp(_spmm_kernel, nondiff_argnums=(3, 4))
+
+
+def _spmm_fwd(indices, weights, x, block_v, block_f):
+    return _spmm_kernel(indices, weights, x, block_v, block_f), (indices, weights, x)
+
+
+def _spmm_bwd(block_v, block_f, res, g):
+    indices, weights, x = res
+    _, vjp = jax.vjp(lambda w, xx: spmm_ref(indices, w, xx), weights, x)
+    return (None, *vjp(g))
+
+
+_spmm.defvjp(_spmm_fwd, _spmm_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("block_v", "block_f"))
+def spmm(indices, weights, x, block_v=128, block_f=128):
+    return _spmm(indices, weights, x, block_v, block_f)
 
 
 def spmm_streamed(indices, weights, x, *, block_rows=4096,

@@ -50,6 +50,7 @@ compile+run.
 from __future__ import annotations
 
 import json
+import logging
 import time
 import warnings
 from collections import OrderedDict
@@ -64,7 +65,7 @@ from ..api import Program, compile as _compile, trace_count
 from ..core.cost_model import GNNLayerWorkload
 from ..core.hw import AcceleratorConfig, DEFAULT_ACCEL, DEFAULT_LATENCY, LatencyModel
 from ..core.schedule import ModelSchedule
-from ..kernels.common import measure_wall
+from ..kernels.common import measure_wall, resolve_use_pallas
 from ..graphs.batching import (
     BucketPolicy,
     GraphBatch,
@@ -94,6 +95,8 @@ from .resilience import (
     default_ladder,
     validate_request,
 )
+
+log = logging.getLogger("repro.runtime")
 
 
 @dataclass(frozen=True)
@@ -264,6 +267,10 @@ class ProgramCache:
         """Non-counting lookup (used to derive tier twins)."""
         return self._programs.get(key)
 
+    def items(self) -> list[tuple[tuple, Program]]:
+        """Non-counting listing, least recently used first."""
+        return list(self._programs.items())
+
     def put(self, key: tuple, prog: Program) -> None:
         self._programs[key] = prog
         self._programs.move_to_end(key)
@@ -293,8 +300,11 @@ class InferenceEngine:
 
     * ``retry`` — bounded backoff per ladder tier
       (:class:`~repro.runtime.resilience.RetryPolicy`);
+    * ``use_pallas`` — whether the preferred tier runs the Pallas kernels
+      (``None``: exactly when JAX's default backend is the TPU);
     * ``ladder`` — explicit degradation tiers (default:
       :func:`~repro.runtime.resilience.default_ladder` of ``use_pallas``);
+      the first step down from a Pallas tier is logged with its cause;
     * ``max_inflight_graphs`` — admission-control cap per ``submit`` call;
       excess requests are shed with ``rejected`` + ``retry_after_s``;
     * ``fault_injector`` — a
@@ -321,7 +331,7 @@ class InferenceEngine:
         policy: BucketPolicy = BucketPolicy(),
         schedule: ModelSchedule | None = None,
         cache_capacity: int = 32,
-        use_pallas: bool = False,
+        use_pallas: bool | None = None,
         readout: str | None = "mean",
         retry: RetryPolicy = RetryPolicy(max_retries=2, backoff_s=0.0),
         ladder: Sequence[Tier] | None = None,
@@ -344,11 +354,12 @@ class InferenceEngine:
         self.hw = hw
         self.policy = policy
         self.schedule = schedule
-        self.use_pallas = use_pallas
+        self.use_pallas = resolve_use_pallas(use_pallas)
         self.readout = readout
         self.retry = retry
         self.ladder = (
-            tuple(ladder) if ladder is not None else default_ladder(use_pallas)
+            tuple(ladder) if ladder is not None
+            else default_ladder(self.use_pallas)
         )
         if not self.ladder:
             raise ValueError("the degradation ladder needs at least one tier")
@@ -418,6 +429,7 @@ class InferenceEngine:
         self._errors: dict[str, int] = {}
         self._n_retries = 0
         self._n_downgrades = 0
+        self._pallas_step_down_logged = False
         self._n_solo_retries = 0
         #: per-bucket micro-batch sequence numbers (fault-injection plans
         #: target (bucket, batch_index); solo-retry batches get their own)
@@ -546,6 +558,11 @@ class InferenceEngine:
                 self._schedules.setdefault(bucket, prog.schedule)
             self.cache.put(key, prog)
         return prog
+
+    def programs(self) -> list[tuple[tuple[int, int, int], Program]]:
+        """The cached Programs, least recently used first, each with the
+        ``(v_bucket, v_total, d_bucket)`` shape it was compiled for."""
+        return [(key[4], prog) for key, prog in self.cache.items()]
 
     # -- ahead-of-time warmup ------------------------------------------------
     def _synthetic_batch(
@@ -1205,6 +1222,7 @@ class InferenceEngine:
                         n_retries += 1
                         self._n_retries += 1
                         self.retry.sleep_for(attempt)
+            self._log_step_down(tier_idx, last)
         assert last is not None
         return (
             None, n_parts, len(self.ladder) - 1, n_retries,
@@ -1455,8 +1473,26 @@ class InferenceEngine:
                         self._n_retries += 1
                         self.retry.sleep_for(attempt)
             # tier exhausted: fall through to the next rung of the ladder
+            self._log_step_down(tier_idx, last)
         assert last is not None
         return None, len(self.ladder) - 1, n_retries, as_serving_error(last)
+
+    def _log_step_down(self, tier_idx: int, cause: BaseException) -> None:
+        """Log the engine's first step down off a Pallas tier, with its
+        cause: a kernel the chip refuses must not hide behind the jnp
+        tiers.  Every step down is still counted in ``n_downgrades``."""
+        tier = self.ladder[tier_idx]
+        if (
+            tier.use_pallas
+            and tier_idx + 1 < len(self.ladder)
+            and not self._pallas_step_down_logged
+        ):
+            self._pallas_step_down_logged = True
+            log.warning(
+                "stepping down the ladder from tier %r to %r: %s: %s",
+                tier.name, self.ladder[tier_idx + 1].name,
+                type(cause).__name__, cause,
+            )
 
     def _attempt(
         self,

@@ -35,7 +35,10 @@ Store layout::
       index.json              # versioned key -> file listing (informational)
       <digest>.program.json   # one Program artifact per key
       traffic.json            # TrafficProfile (bucket heat across lives)
-      jax-cache/              # XLA persistent compilation cache (opt-in)
+
+``jax_cache=True`` turns on the XLA persistent compilation cache, which
+lives where :func:`enable_persistent_compilation_cache` puts it, never
+under the store root.
 """
 from __future__ import annotations
 
@@ -52,9 +55,12 @@ from ..graphs.batching import TrafficProfile
 
 STORE_FORMAT = "repro.store/v1"
 
-#: environment override for the XLA persistent compilation cache location
+#: JAX's own setting for the persistent compilation cache's directory
 #: (see :func:`enable_persistent_compilation_cache`).
-JAX_CACHE_ENV = "REPRO_JAX_CACHE_DIR"
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: where the compile cache goes when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: ``.jax_cache/`` at the root of the checkout (git-ignored).
+DEFAULT_JAX_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 _INDEX = "index.json"
 _PROFILE = "traffic.json"
@@ -143,13 +149,7 @@ class ProgramStore:
         self._lock = threading.Lock()
         self._index: dict[str, dict] = self._load_index()
         if jax_cache:
-            # co-locate the XLA cache with the store unless the operator
-            # pointed REPRO_JAX_CACHE_DIR somewhere else (CI does, so the
-            # two caches can be restored independently)
-            enable_persistent_compilation_cache(
-                None if os.environ.get(JAX_CACHE_ENV)
-                else self.root / "jax-cache"
-            )
+            enable_persistent_compilation_cache()
 
     # -- index ---------------------------------------------------------------
     def _load_index(self) -> dict[str, dict]:
@@ -313,25 +313,32 @@ class ProgramStore:
         }
 
 
-def enable_persistent_compilation_cache(cache_dir=None) -> Path:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so the
-    XLA executables behind every jitted ``Program.run`` survive restarts.
+def enable_persistent_compilation_cache() -> Path:
+    """Turn on JAX's persistent compilation cache so the XLA executables
+    behind every jitted ``Program.run`` survive restarts; returns its
+    directory.
 
-    Resolution order: explicit ``cache_dir`` argument, the
-    ``REPRO_JAX_CACHE_DIR`` environment variable, then
-    ``~/.cache/repro/jax-cache``.  The min-compile-time threshold is
-    dropped to zero because serving executables on small bucket shapes
-    compile fast but add up across a fleet of buckets — exactly the
-    entries the default 1 s threshold would skip.  Returns the directory.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX takes the directory
+    from it and this function sets none.  Otherwise the cache goes to
+    :data:`DEFAULT_JAX_CACHE_DIR`, one fixed path, so a later run finds
+    what an earlier one wrote (a directory named per run would never hit).
+    The min-compile-time threshold is dropped to zero because serving
+    executables on small bucket shapes compile fast but add up across a
+    fleet of buckets — exactly the entries the default 1 s threshold would
+    skip.
     """
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    d = Path(
-        cache_dir
-        or os.environ.get(JAX_CACHE_ENV)
-        or Path.home() / ".cache" / "repro" / "jax-cache"
-    ).expanduser()
-    d.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(d))
+    env = os.environ.get(JAX_CACHE_ENV)
+    if env:
+        d = Path(env)
+    else:
+        d = DEFAULT_JAX_CACHE_DIR
+        d.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(d))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # a compile before this call may have initialized the cache without
+    # a directory; start it afresh with the settings above
+    compilation_cache.reset_cache()
     return d

@@ -12,6 +12,7 @@ from repro.kernels.fused_agg_cmb import fused_agg_cmb, fused_ref
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.gemm_dataflow import DATAFLOWS, gemm_ref
 from repro.kernels.gemm_dataflow.ops import gemm
+from repro.kernels.common import default_interpret, lane_block_f, row_block
 from repro.kernels.spmm import spmm, spmm_ref
 
 RNG = np.random.default_rng(42)
@@ -71,6 +72,26 @@ def random_ell(v, max_deg, seed=0):
     return jnp.asarray(idx), jnp.asarray(wts)
 
 
+def fixed_ell(v, d, seed=0):
+    """ELL rows of exactly ``d`` random slots (D need not be a multiple
+    of the 8-row sublane tile)."""
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, v, size=(v, d)).astype(np.int32)
+    wts = rng.normal(size=(v, d)).astype(np.float32)
+    return jnp.asarray(idx), jnp.asarray(wts)
+
+
+#: (v, f, d, block_v, block_f): F not lane-aligned, block_f below the
+#: 128-lane tile, D not a multiple of 8 — each legalized by the wrappers
+TPU_BLOCK_CASES = [
+    (40, 3703, 5, 16, 128),
+    (40, 300, 3, 8, 8),
+    (33, 16, 7, 128, 8),
+    (70, 130, 13, 12, None),
+]
+TPU_BLOCK_IDS = ["f3703", "f300-block_f8", "f16-block_f8", "f130-d13"]
+
+
 class TestSpmm:
     @pytest.mark.parametrize("v,f,deg", [(64, 32, 4), (200, 96, 8), (17, 5, 3)])
     def test_matches_oracle(self, v, f, deg):
@@ -103,6 +124,17 @@ class TestSpmm:
         rng = np.random.default_rng(seed)
         x = rand((v, f), rng=rng)
         out = spmm(idx, wts, x, block_v=32, block_f=32)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(spmm_ref(idx, wts, x)), rtol=1e-4, atol=1e-4
+        )
+
+
+    @pytest.mark.parametrize("v,f,d,block_v,block_f", TPU_BLOCK_CASES,
+                             ids=TPU_BLOCK_IDS)
+    def test_tpu_block_legalization(self, v, f, d, block_v, block_f):
+        idx, wts = fixed_ell(v, d, seed=f)
+        x = rand((v, f))
+        out = spmm(idx, wts, x, block_v=block_v, block_f=block_f or 128)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(spmm_ref(idx, wts, x)), rtol=1e-4, atol=1e-4
         )
@@ -144,6 +176,105 @@ class TestFusedAggCmb:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(fused_ref(idx, wts, x, w)), rtol=2e-4, atol=2e-4
         )
+
+
+    @pytest.mark.parametrize("v,f,d,block_v,block_f", TPU_BLOCK_CASES,
+                             ids=TPU_BLOCK_IDS)
+    def test_tpu_block_legalization(self, v, f, d, block_v, block_f):
+        idx, wts = fixed_ell(v, d, seed=f)
+        x, w = rand((v, f)), rand((f, 6))
+        out = fused_agg_cmb(idx, wts, x, w, band_size=block_v, block_f=block_f)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(fused_ref(idx, wts, x, w)), rtol=2e-4, atol=2e-3
+        )
+
+
+class TestReverseMode:
+    """Gradients through the Pallas aggregation kernels (their VJPs are
+    the jnp oracles', since ``pallas_call`` has no transpose rule)."""
+
+    @pytest.mark.parametrize("kernel", ["spmm", "fused_agg_cmb"])
+    def test_grad_matches_oracle(self, kernel):
+        idx, wts = random_ell(48, 5, seed=3)
+        x, w = rand((48, 20)), rand((20, 7))
+        if kernel == "spmm":
+            fns = (lambda a, b, c: spmm(idx, a, b, block_v=16, block_f=8) @ c,
+                   lambda a, b, c: spmm_ref(idx, a, b) @ c)
+        else:
+            fns = (lambda a, b, c: fused_agg_cmb(idx, a, b, c, band_size=16),
+                   lambda a, b, c: fused_ref(idx, a, b, c))
+        got, want = (
+            jax.grad(lambda a, b, c: (fn(a, b, c) ** 2).sum(),
+                     argnums=(0, 1, 2))(wts, x, w)
+            for fn in fns
+        )
+        for gg, ww in zip(got, want):
+            np.testing.assert_allclose(np.asarray(gg), np.asarray(ww),
+                                       rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("policy", ["seq", "sp_opt"])
+    def test_train_step_through_pallas(self, policy):
+        import repro
+        from repro.core import ModelSchedule, resolve_kernel_key
+        from repro.gnn import GNNConfig
+        from repro.gnn.model import make_node_classification_task
+
+        rng = np.random.default_rng(7)
+        graph = from_edges(60, rng.integers(0, 60, 200), rng.integers(0, 60, 200))
+        cfg = GNNConfig(kind="gcn", f_in=20, hidden=8, n_classes=3)
+        x, labels, mask = make_node_classification_task(graph, 20, 3)
+        sched = ModelSchedule.from_policies(policy, "AC", list(cfg.dims))
+        steps = []
+        for use_pallas in (True, False):
+            prog = repro.compile(cfg, graph=graph, schedule=sched,
+                                 use_pallas=use_pallas)
+            keys = {resolve_kernel_key(s.policy, s.order, s.use_pallas)[2]
+                    for s in prog.specs}
+            assert keys == {use_pallas}
+            steps.append(prog.train_step(prog.init(jax.random.PRNGKey(0)),
+                                         x, labels, mask))
+        (loss_p, new_p), (loss_j, new_j) = steps
+        np.testing.assert_allclose(float(loss_p), float(loss_j), rtol=1e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(new_p),
+                        jax.tree_util.tree_leaves(new_j)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+
+class TestTpuBlocks:
+    """The block arithmetic the kernels rely on to compile for the TPU."""
+
+    @pytest.mark.parametrize(
+        "block_f,f,rows,want",
+        [
+            (None, 3703, 4096, 128),  # capped by the table buffer budget
+            (8, 3703, 4096, 128),  # rounded up to whole lanes
+            (256, 3703, 512, 256),
+            (None, 16, 4096, 16),  # narrower than a lane: full dimension
+            (32, 200, 64, 128),
+            (None, 200, 64, 200),
+        ],
+    )
+    def test_lane_block_f(self, block_f, f, rows, want):
+        assert lane_block_f(block_f, f, rows) == want
+
+    @pytest.mark.parametrize(
+        "block_v,v,d,want",
+        [(128, 4096, 64, 128), (128, 20, 8, 20), (7, 100, 8, 8),
+         (12, 100, 8, 16), (128, 4096, 1000, 32)],
+    )
+    def test_row_block(self, block_v, v, d, want):
+        assert row_block(block_v, v, d) == want
+
+    @pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False)])
+    def test_default_interpret_by_backend(self, monkeypatch, backend, want):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert default_interpret() is want
+
+    def test_default_interpret_refuses_other_backends(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            default_interpret()
 
 
 class TestFlashAttention:

@@ -323,6 +323,20 @@ class TestDegradation:
         stats = eng.stats()
         assert stats.n_downgrades == 1 and stats.n_degraded == 1
 
+    def test_pallas_step_down_logged_once_with_cause(self, params, caplog):
+        """A Pallas tier the backend refuses must not degrade in silence:
+        the first step down is logged with its cause, every one counted."""
+        eng = make_engine(params, use_pallas=True)
+        with kill_pallas(), caplog.at_level("WARNING", logger="repro.runtime"):
+            a = eng.submit([make_request(16, seed=0, rid=0)])
+            b = eng.submit([make_request(32, seed=1, rid=1)])
+        assert [r.tier for r in a + b] == ["jnp+searched"] * 2
+        assert eng.stats().n_downgrades == 2
+        logged = [r for r in caplog.records if "stepping down" in r.getMessage()]
+        assert len(logged) == 1
+        assert "pallas+searched" in logged[0].getMessage()
+        assert "KernelFault" in logged[0].getMessage()
+
     @pytest.mark.parametrize("policy", ["seq", "sp_generic", "sp_opt"])
     @pytest.mark.parametrize("order", ["AC", "CA"])
     def test_degraded_numerics_match_reference(self, params, policy, order):

@@ -92,6 +92,53 @@ class TestExecutableCache:
             compiled(g).bind(g, pad_degree=1)
 
 
+class TestKernelFamilyDefault:
+    """Pallas or jnp follows the backend unless the caller says."""
+
+    def test_engine_default_follows_backend(self):
+        from repro.runtime import default_ladder
+
+        on_tpu = jax.default_backend() == "tpu"
+        eng = InferenceEngine(DIMS)
+        assert eng.use_pallas is on_tpu
+        assert eng.ladder == default_ladder(on_tpu)
+
+    @pytest.mark.parametrize("flag", [True, False])
+    def test_explicit_flag_is_honoured(self, flag):
+        assert InferenceEngine(DIMS, use_pallas=flag).use_pallas is flag
+        wls = [GNNLayerWorkload(ring_graph(16).nnz, fi, fo) for fi, fo in DIMS]
+        prog = repro.compile(wls, schedule=SCHEDULE, use_pallas=flag)
+        assert prog.use_pallas is flag
+
+    def test_compile_default_follows_backend(self):
+        prog = compiled(ring_graph(16))
+        assert prog.use_pallas is (jax.default_backend() == "tpu")
+
+    @pytest.mark.parametrize(
+        "policy,order,want",
+        [("seq", "CA", ("seq", "CA", True)),
+         ("sp_opt", "AC", ("sp_opt", "AC", True)),
+         ("sp_opt", "CA", ("sp_opt", "CA", False)),
+         ("sp_generic", "AC", ("sp_generic", "AC", False))],
+    )
+    def test_resolve_kernel_key_names_the_jnp_fallback(self, policy, order, want):
+        from repro.core import resolve_kernel_key
+
+        assert resolve_kernel_key(policy, order, True) == want
+        assert resolve_kernel_key(policy, order, False) == (policy, order, False)
+
+    def test_lowered_is_the_executable_run_calls(self, params):
+        g = ring_graph(24, chords=6)
+        prog = compiled(g)
+        x = jnp.ones((g.n_nodes, DIMS[0][0]), jnp.float32)
+        out = prog.lowered(params, x).compile()(
+            params, prog.adj.indices, prog.adj.weights, x,
+            jnp.zeros(0, jnp.int32),
+        )
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(prog.run(params, x)))
+
+
 class TestSegmentReadout:
     def test_readout_reduces_known_values(self):
         h = jnp.asarray([[1.0], [3.0], [10.0], [99.0]])

@@ -5,6 +5,7 @@ same store — serves bit-identical outputs with zero mapper searches and,
 after precompile(), zero new XLA traces on its first request."""
 import json
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,12 @@ import repro
 from repro.core import GNNLayerWorkload
 from repro.core.schedule import ModelSchedule
 from repro.graphs import BucketPolicy, TrafficProfile, from_edges
-from repro.runtime import ProgramStore, key_digest, store_key
+from repro.runtime import (
+    ProgramStore,
+    enable_persistent_compilation_cache,
+    key_digest,
+    store_key,
+)
 from repro.runtime.engine import InferenceEngine, Request
 
 DIMS = [(12, 16), (16, 4)]
@@ -318,3 +324,38 @@ class TestStatsSplit:
             stats.search_s + stats.trace_s
         )
         assert stats.n_searches >= 1
+
+
+class TestCompilationCachePath:
+    """The XLA compile cache is placed from outside, or at one fixed path
+    in the checkout — never under a store root or a temporary name."""
+
+    @pytest.fixture
+    def restore_jax_cache(self):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
+        saved = {k: getattr(jax.config, k) for k in keys}
+        yield
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+    def test_env_dir_is_left_to_jax(self, tmp_path, monkeypatch,
+                                    restore_jax_cache):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_persistent_compilation_cache() == tmp_path / "cc"
+        ProgramStore(tmp_path / "store", jax_cache=True)
+        assert jax.config.jax_compilation_cache_dir == before
+        assert sorted(p.name for p in (tmp_path / "store").iterdir()) == []
+
+    def test_default_is_fixed_path_in_checkout(self, tmp_path, monkeypatch,
+                                               restore_jax_cache):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = Path(repro.__file__).resolve().parents[2] / ".jax_cache"
+        assert enable_persistent_compilation_cache() == want
+        ProgramStore(tmp_path / "store", jax_cache=True)
+        assert jax.config.jax_compilation_cache_dir == str(want)
+        assert sorted(p.name for p in (tmp_path / "store").iterdir()) == []
