@@ -6,13 +6,20 @@ schedule (or accept one), lower it to executable kernel knobs, and get a
 frozen :class:`repro.api.Program` with ``run``/``loss``/``stats`` and a
 cacheable ``save``/``load`` JSON artifact.
 """
-from .api import Program, compile, trace_count, workload_fingerprint
+from .api import (
+    Program,
+    compile,
+    compile_count,
+    trace_count,
+    workload_fingerprint,
+)
 from .core.hw import LatencyModel
 
 __all__ = [
     "LatencyModel",
     "Program",
     "compile",
+    "compile_count",
     "trace_count",
     "workload_fingerprint",
 ]

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import zlib
 from dataclasses import asdict, dataclass, field, replace
 from pathlib import Path
@@ -79,6 +80,26 @@ def _note_trace() -> None:
 def trace_count() -> int:
     """Process-wide count of XLA traces taken by ``Program.run``."""
     return _TRACE_COUNT
+
+
+#: backend compiles per thread: a recompile without a retrace (an input
+#: placed differently, an evicted executable) never moves ``trace_count``,
+#: and each device worker compiles on its own thread
+_COMPILES = threading.local()
+
+
+def _note_compile(event: str, duration: float, **kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILES.n = getattr(_COMPILES, "n", 0) + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_note_compile)
+
+
+def compile_count() -> int:
+    """Backend compiles (or persistent-cache loads) of any jitted function
+    taken on the calling thread."""
+    return getattr(_COMPILES, "n", 0)
 
 
 def workload_fingerprint(workloads: Sequence[GNNLayerWorkload]) -> dict:

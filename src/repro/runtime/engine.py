@@ -49,6 +49,7 @@ compile+run.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import time
@@ -60,8 +61,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from ..api import Program, compile as _compile, trace_count
+from ..api import Program, compile as _compile, compile_count, trace_count
 from ..core.cost_model import GNNLayerWorkload
 from ..core.hw import AcceleratorConfig, DEFAULT_ACCEL, DEFAULT_LATENCY, LatencyModel
 from ..core.schedule import ModelSchedule
@@ -172,9 +174,6 @@ class EngineStats:
     graphs_per_sec: float
     p50_ms: float
     p99_ms: float
-    #: ``search_s + trace_s`` — kept as the historical aggregate so older
-    #: dashboards/benchmark JSON keep a comparable column.
-    compile_s: float
     search_s: float  # mapper search + Program packaging (cold buckets)
     trace_s: float  # wall of executions that took new XLA traces/compiles
     cache_hits: int
@@ -197,6 +196,7 @@ class EngineStats:
     n_partitioned: int = 0  # oversized requests served via a partition plan
     partition_wall_s: float = 0.0  # wall spent inside the partitioned lane
     partition_plans: dict = field(default_factory=dict)  # plan kind -> count
+    n_compiles: int = 0  # backend compiles taken by executions and primes
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -282,6 +282,11 @@ class ProgramCache:
 def _chunks(seq: list, size: int):
     for i in range(0, len(seq), size):
         yield seq[i : i + size]
+
+
+#: process-wide micro-batch ids: the ``batch`` of the ``repro.assemble``,
+#: ``repro.stage``, ``repro.bind`` and ``repro.execute`` profiler spans
+next_batch_id = itertools.count().__next__
 
 
 class InferenceEngine:
@@ -422,6 +427,7 @@ class InferenceEngine:
         self._wall_s = 0.0
         self._search_s = 0.0  # mapper search + Program packaging
         self._trace_s = 0.0  # wall of executions that took new XLA traces
+        self._n_compiles = 0  # backend compiles taken by those executions
         self._n_searches = 0  # mapper searches actually run
         self._status_counts = {s: 0 for s in
                                (STATUS_OK, STATUS_REJECTED, STATUS_FAILED,
@@ -641,6 +647,7 @@ class InferenceEngine:
                 self._buckets_seen.add((v_bucket, d_bucket))
                 prog = self._program_for(batch, tier)
                 bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
+                compiles0 = compile_count()
                 t_run = time.perf_counter()
                 # prime with this engine's donate flag: the jit-executable
                 # cache keys on it, so a donate=False (async) engine must
@@ -656,7 +663,9 @@ class InferenceEngine:
                         readout=self.readout,
                         donate=self.donate,
                     )
-                if n_new:
+                n_compiles = compile_count() - compiles0
+                self._n_compiles += n_compiles
+                if n_new or n_compiles:
                     self._trace_s += time.perf_counter() - t_run
                 rep.n_shapes += 1
                 rep.n_traces += n_new
@@ -1025,6 +1034,7 @@ class InferenceEngine:
         t_arrival: Sequence[float] | None = None,
         *,
         pre: tuple[GraphBatch, "jax.Array"] | None = None,
+        batch_id: int | None = None,
     ) -> list[Result]:
         """Serve one *pre-admitted*, same-bucket group of requests — the
         async front-end's batching-window flush path.
@@ -1043,6 +1053,8 @@ class InferenceEngine:
         host->device transfer overlaps queueing).  It is used only when
         every request in the group is still live — a deadline drop
         changes the batch composition and falls back to re-assembly.
+        ``batch_id`` is the micro-batch id the front-end gave the group
+        when it flushed the window (:data:`next_batch_id`).
 
         Same fault contract as :meth:`submit`: never raises for a
         per-request cause.
@@ -1073,7 +1085,9 @@ class InferenceEngine:
                         requests, live, bucket_key, results,
                         t_arrival=t_arrival,
                         pre=pre if live == idxs else None,
+                        batch_id=batch_id,
                     )
+                    batch_id = None  # a further chunk is a batch of its own
         self._wall_s += time.perf_counter() - t0
         return results  # type: ignore[return-value]
 
@@ -1253,7 +1267,7 @@ class InferenceEngine:
             )
             prog = None
             pending = []
-            traces_before = trace_count()
+            traces_before, compiles_before = trace_count(), compile_count()
             t_run = time.perf_counter()
             for part in parts:
                 batch = assemble([part.graph], sub_policy)
@@ -1277,7 +1291,9 @@ class InferenceEngine:
                 np.asarray(jax.block_until_ready(o))[:n_own]
                 for o, n_own in pending
             ]
-            if trace_count() > traces_before:
+            n_compiles = compile_count() - compiles_before
+            self._n_compiles += n_compiles
+            if n_compiles or trace_count() > traces_before:
                 self._trace_s += time.perf_counter() - t_run
             h = np.concatenate(slices, axis=0)
             n_parts = len(parts)
@@ -1357,26 +1373,33 @@ class InferenceEngine:
         t_arrival: Sequence[float],
         solo: bool = False,
         pre: tuple[GraphBatch, "jax.Array"] | None = None,
+        batch_id: int | None = None,
     ) -> None:
         """Assemble and execute one micro-batch down the ladder; on a
         whole-batch fault, quarantine by re-running each member solo.
 
         ``pre`` skips assembly: the front-end already built the batch and
         staged its features on this engine's device (quarantine solo
-        re-runs always re-assemble — their composition differs)."""
+        re-runs always re-assemble — their composition differs).
+        ``batch_id`` tags the batch's spans; a new one is drawn without."""
         t0 = time.perf_counter()
+        if batch_id is None:
+            batch_id = next_batch_id()
         if pre is not None:
             batch, x_in = pre
         else:
-            batch = assemble([requests[i].graph for i in idxs], self.policy)
-            x_in = batch.batch_features([requests[i].x for i in idxs])
+            with TraceAnnotation("repro.assemble", batch=batch_id):
+                batch = assemble(
+                    [requests[i].graph for i in idxs], self.policy
+                )
+                x_in = batch.batch_features([requests[i].x for i in idxs])
         self.profile.record_batch(bucket_key, batch.slots)
         rids = [requests[i].rid for i in idxs]
         batch_index = self._batch_seq.get(bucket_key, 0)
         self._batch_seq[bucket_key] = batch_index + 1
 
         outs, tier_idx, n_retries, err = self._execute_ladder(
-            batch, x_in, rids, bucket_key, batch_index
+            batch, x_in, rids, bucket_key, batch_index, batch_id
         )
         dt = time.perf_counter() - t0
         t_done = time.perf_counter()
@@ -1445,6 +1468,7 @@ class InferenceEngine:
         rids: list[int],
         bucket_key: tuple[int, int],
         batch_index: int,
+        batch_id: int,
     ):
         """Walk the degradation ladder with bounded retries per tier.
 
@@ -1463,7 +1487,8 @@ class InferenceEngine:
             for attempt in range(self.retry.max_attempts):
                 try:
                     outs = self._attempt(
-                        batch, x_in, rids, bucket_key, batch_index, tier
+                        batch, x_in, rids, bucket_key, batch_index, batch_id,
+                        tier,
                     )
                     return outs, tier_idx, n_retries, None
                 except Exception as e:  # noqa: BLE001 — isolate any fault
@@ -1501,11 +1526,13 @@ class InferenceEngine:
         rids: list[int],
         bucket_key: tuple[int, int],
         batch_index: int,
+        batch_id: int,
         tier: Tier,
     ) -> list[np.ndarray]:
         """One execution attempt on one tier (the unit of retry)."""
         prog = self._program_for(batch, tier)
-        bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
+        with TraceAnnotation("repro.bind", batch=batch_id):
+            bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
         corrupt = None
         if self.injector is not None:
             corrupt = self.injector.on_run(
@@ -1516,31 +1543,34 @@ class InferenceEngine:
         # a staged buffer must survive retries and lower ladder tiers;
         # donating it would leave the next attempt with a dead buffer
         donate = self.donate and not staged
-        traces_before = trace_count()
+        traces_before, compiles_before = trace_count(), compile_count()
         t_run = time.perf_counter()
-        if self.readout is None:
-            out = bound.run(self.params, x, donate=donate)
-        else:
-            # readout over the padded slot count, not n_graphs: the
-            # executable shape then depends only on the bucket, so tail
-            # batches at any fill level reuse it (pad segments are sliced
-            # off below)
-            out = bound.run(
-                self.params,
-                x,
-                segment_ids=jnp.asarray(batch.segment_ids),
-                num_segments=batch.slots,
-                readout=self.readout,
-                donate=donate,
-            )
-        arr = np.asarray(jax.block_until_ready(out))
+        with TraceAnnotation("repro.execute", batch=batch_id):
+            if self.readout is None:
+                out = bound.run(self.params, x, donate=donate)
+            else:
+                # readout over the padded slot count, not n_graphs: the
+                # executable shape then depends only on the bucket, so
+                # tail batches at any fill level reuse it (pad segments
+                # are sliced off below)
+                out = bound.run(
+                    self.params,
+                    x,
+                    segment_ids=jnp.asarray(batch.segment_ids),
+                    num_segments=batch.slots,
+                    readout=self.readout,
+                    donate=donate,
+                )
+            arr = np.asarray(jax.block_until_ready(out))
         wall = time.perf_counter() - t_run
-        traced = trace_count() > traces_before
-        if traced:
-            # first execution on a cold shape: this wall is dominated by
-            # the XLA trace + compile (or the persistent-cache load), so
-            # attribute it to trace_s — that is exactly what precompile()
-            # and the compilation cache save a revived engine.
+        n_compiles = compile_count() - compiles_before
+        self._n_compiles += n_compiles
+        cold = n_compiles > 0 or trace_count() > traces_before
+        if cold:
+            # this wall is dominated by an XLA trace and/or a backend
+            # compile (or a persistent-cache load), so attribute it to
+            # trace_s — that is exactly what precompile() and the
+            # compilation cache save a revived engine.
             self._trace_s += wall
         if corrupt == "nan":
             arr = self.injector.corrupt_output(arr)
@@ -1549,7 +1579,7 @@ class InferenceEngine:
                 f"non-finite values in the output of bucket {bucket_key} "
                 f"batch {batch_index} (tier {tier.name}, rids {rids})"
             )
-        if not traced and corrupt is None:
+        if not cold and corrupt is None:
             # clean warm run: fold the measured wall into the traffic
             # profile's observation ledger keyed by the schedule that
             # produced it — the feedback half of the predicted<->measured
@@ -1577,7 +1607,6 @@ class InferenceEngine:
                 float(np.median(self._batch_walls)) * 1e3
                 if self._batch_walls else 0.0
             ),
-            compile_s=self._search_s + self._trace_s,
             search_s=self._search_s,
             trace_s=self._trace_s,
             cache_hits=self.cache.hits,
@@ -1599,4 +1628,5 @@ class InferenceEngine:
             n_partitioned=self._n_partitioned,
             partition_wall_s=self._partition_wall_s,
             partition_plans=dict(self._partition_plans),
+            n_compiles=self._n_compiles,
         )

@@ -59,6 +59,7 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..gnn.pp import mesh_devices
 from ..graphs.batching import TrafficProfile, assemble
@@ -68,6 +69,7 @@ from .engine import (
     PrecompileReport,
     Request,
     Result,
+    next_batch_id,
 )
 from .resilience import (
     STATUS_DEGRADED,
@@ -106,6 +108,12 @@ class AsyncEngineStats:
     n_flushes_full: int = 0  # windows flushed because they filled
     n_flushes_deadline: int = 0  # windows flushed by the window_ms clock
     max_inflight: int = 0  # high-water mark of queued+running graphs
+    #: Σ (flush - arrival) over the requests of every flushed window
+    window_wait_s: float = 0.0
+    n_window_waits: int = 0
+    #: Σ (worker pops the group - dispatch) over every dispatched group
+    inbox_wait_s: float = 0.0
+    n_groups: int = 0
     errors: dict = field(default_factory=dict)
     placement: dict = field(default_factory=dict)  # "VxD" -> [device labels]
     per_device: dict = field(default_factory=dict)  # label -> EngineStats dict
@@ -236,7 +244,8 @@ class BucketPlacer:
 class _Window:
     """One open batching window: same-bucket requests waiting to flush."""
 
-    __slots__ = ("bucket", "requests", "arrivals", "futures", "deadline")
+    __slots__ = ("bucket", "requests", "arrivals", "futures", "deadline",
+                 "batch_id")
 
     def __init__(self, bucket: tuple[int, int], deadline: float):
         self.bucket = bucket
@@ -244,6 +253,7 @@ class _Window:
         self.arrivals: list[float] = []
         self.futures: list[Future] = []
         self.deadline = deadline  # perf_counter time to force-flush
+        self.batch_id: int | None = None  # drawn when the window flushes
 
 
 class _DeviceWorker(threading.Thread):
@@ -259,12 +269,12 @@ class _DeviceWorker(threading.Thread):
         self.device = device
         self.engine = engine
         self.owner = owner
-        self.inbox: "list" = []
+        self.inbox: "list" = []  # (dispatch time, item)
         self.cv = threading.Condition()
 
     def dispatch(self, item) -> None:
         with self.cv:
-            self.inbox.append(item)
+            self.inbox.append((time.perf_counter(), item))
             self.cv.notify()
 
     def run(self) -> None:
@@ -279,15 +289,18 @@ class _DeviceWorker(threading.Thread):
                 with self.cv:
                     while not self.inbox:
                         self.cv.wait()
-                    item = self.inbox.pop(0)
+                    t_dispatch, item = self.inbox.pop(0)
                 if item is None:
                     return
                 kind, payload, fut = item
                 try:
                     if kind == "group":
-                        reqs, arrivals, pre = payload
+                        self.owner._note_inbox_wait(
+                            time.perf_counter() - t_dispatch
+                        )
+                        reqs, arrivals, pre, batch_id = payload
                         out = self.engine.serve_group(
-                            reqs, arrivals, pre=pre
+                            reqs, arrivals, pre=pre, batch_id=batch_id
                         )
                     else:  # "call": run an arbitrary thunk on this device
                         out = payload()
@@ -368,6 +381,10 @@ class AsyncEngine:
         self._n_requests = 0
         self._n_flushes_full = 0
         self._n_flushes_deadline = 0
+        self._window_wait_s = 0.0
+        self._n_window_waits = 0
+        self._inbox_wait_s = 0.0
+        self._n_groups = 0
         self._fe_latencies: list[float] = []  # front-end rejections
         self._fe_status = {s: 0 for s in
                            (STATUS_OK, STATUS_REJECTED, STATUS_FAILED,
@@ -484,7 +501,8 @@ class AsyncEngine:
             if self._wall_t0 is None:
                 self._wall_t0 = t_arrival
             self._n_requests += 1
-            err = self._admission_error(req)
+            with TraceAnnotation("repro.admit", rid=req.rid):
+                err = self._admission_error(req)
             if (
                 err is not None
                 and isinstance(err, OversizedGraph)
@@ -570,6 +588,10 @@ class AsyncEngine:
         if win is None or not win.requests:
             return None
         widx = self.placer.pick(bucket, len(win.requests))
+        win.batch_id = next_batch_id()
+        now = time.perf_counter()
+        self._window_wait_s += sum(now - t for t in win.arrivals)
+        self._n_window_waits += len(win.arrivals)
         if reason == "full":
             self._n_flushes_full += 1
         else:
@@ -585,16 +607,22 @@ class AsyncEngine:
             pre = None
             if len(win.requests) <= self.policy.max_graphs:
                 try:
-                    batch = assemble(
-                        [r.graph for r in win.requests], self.policy
-                    )
-                    x_np = batch.batch_features([r.x for r in win.requests])
+                    with TraceAnnotation("repro.assemble", batch=win.batch_id):
+                        batch = assemble(
+                            [r.graph for r in win.requests], self.policy
+                        )
+                        x_np = batch.batch_features(
+                            [r.x for r in win.requests]
+                        )
                     # place (don't commit) the feature block on the target
                     # device: committed-ness is part of the jit dispatch
                     # key, and precompile's prime warms the uncommitted
                     # variant — a committed device_put here would pay a
                     # fresh XLA compile per shape despite the warm cache
-                    with jax.default_device(worker.device):
+                    with (
+                        TraceAnnotation("repro.stage", batch=win.batch_id),
+                        jax.default_device(worker.device),
+                    ):
                         pre = (batch, jax.numpy.asarray(x_np))
                 except Exception:
                     pre = None  # fall back to in-engine assembly
@@ -602,9 +630,16 @@ class AsyncEngine:
             done.add_done_callback(
                 self._make_resolver(widx, win.futures, len(win.requests))
             )
-            worker.dispatch(
-                ("group", (win.requests, win.arrivals, pre), done)
-            )
+            worker.dispatch((
+                "group",
+                (win.requests, win.arrivals, pre, win.batch_id),
+                done,
+            ))
+
+    def _note_inbox_wait(self, wait_s: float) -> None:
+        with self._lock:
+            self._inbox_wait_s += wait_s
+            self._n_groups += 1
 
     def _make_resolver(self, widx: int, futures: list, n: int):
         def _resolve(done: "Future") -> None:
@@ -743,6 +778,12 @@ class AsyncEngine:
             n_full = self._n_flushes_full
             n_deadline = self._n_flushes_deadline
             max_inflight = self._max_inflight
+            waits = dict(
+                window_wait_s=self._window_wait_s,
+                n_window_waits=self._n_window_waits,
+                inbox_wait_s=self._inbox_wait_s,
+                n_groups=self._n_groups,
+            )
         per_device: dict[str, EngineStats] = {}
         n_served = 0
         for w in self.workers:
@@ -771,6 +812,7 @@ class AsyncEngine:
             n_flushes_full=n_full,
             n_flushes_deadline=n_deadline,
             max_inflight=max_inflight,
+            **waits,
             errors=errors,
             placement=self.placement(),
             per_device={k: v.as_dict() for k, v in per_device.items()},

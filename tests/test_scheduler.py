@@ -242,6 +242,25 @@ def test_async_per_request_latency_includes_queue_wait(params):
     assert r.latency_s >= 0.05
 
 
+def test_async_window_and_inbox_waits_are_counted(params):
+    """A lone request waits out its whole window, and its flushed group is
+    counted once on its way through the worker's inbox."""
+    req = _stream(1, names=("mutag",))[0]
+    window_ms = 40.0
+    with AsyncEngine(DIMS, params, window_ms=window_ms) as a:
+        a.submit([req])  # warm the bucket
+        st0 = a.stats()
+        r = a.submit_async(
+            Request(graph=req.graph, x=req.x, rid=1)
+        ).result(timeout=60)
+        st = a.stats()
+    assert r.status == "ok"
+    assert st.n_window_waits - st0.n_window_waits == 1
+    assert st.window_wait_s - st0.window_wait_s >= 0.9 * window_ms / 1e3
+    assert st.n_groups - st0.n_groups == 1
+    assert 0.0 <= st.inbox_wait_s - st0.inbox_wait_s < r.latency_s
+
+
 def test_async_precompile_warms_assigned_buckets(tmp_path, params):
     """precompile() on a revived engine loads from the shared store and
     leaves the first real request trace-free (PR 7 contract)."""

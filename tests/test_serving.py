@@ -407,6 +407,50 @@ class TestMeasuredRerank:
         assert (v, d) in eng._buckets_seen and n >= 1 and tot > 0
         assert eng.profile.mean_wall((v, d), slots, digest) > 0
 
+    def test_compile_without_a_trace_is_cold(self, monkeypatch):
+        """A backend compile that takes no new trace (an executable
+        evicted, or an input placed differently) is counted, charged to
+        trace_s and never logged as a warm wall."""
+        eng = self.engine()
+        reqs = [make_request(12, seed=i, rid=i) for i in range(4)]
+        eng.submit(reqs)  # cold
+        eng.submit(reqs)  # warm: one observation
+        observed = dict(eng.profile.observed)
+        st0 = eng.stats()
+        run = repro.Program.run
+
+        def compiling_run(self, *a, **k):
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", 1e-3
+            )
+            return run(self, *a, **k)
+
+        monkeypatch.setattr(repro.Program, "run", compiling_run)
+        traces = repro.trace_count()
+        assert all(r.ok for r in eng.submit(reqs))
+        st = eng.stats()
+        assert repro.trace_count() == traces
+        assert st.n_compiles - st0.n_compiles == st.n_batches - st0.n_batches
+        assert st.trace_s > st0.trace_s
+        assert eng.profile.observed == observed
+
+    def test_compile_count_is_per_thread(self):
+        import threading
+
+        def compile_on_thread():
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", 1e-3
+            )
+
+        before = repro.compile_count()
+        t = threading.Thread(target=compile_on_thread)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert repro.compile_count() == before
+        compile_on_thread()
+        assert repro.compile_count() == before + 1
+
     def test_rerank_is_trace_free_on_request_path(self):
         eng = self.engine()
         reqs = [make_request(12, seed=i, rid=i) for i in range(4)]

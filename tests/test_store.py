@@ -320,9 +320,6 @@ class TestStatsSplit:
         stats = engine.stats()
         assert stats.search_s > 0.0, "a cold engine ran the mapper"
         assert stats.trace_s > 0.0, "a cold engine took XLA traces"
-        assert stats.compile_s == pytest.approx(
-            stats.search_s + stats.trace_s
-        )
         assert stats.n_searches >= 1
 
 
