@@ -16,9 +16,11 @@ SUBLANE, LANE = 8, 128
 TABLE_BLOCK_BYTES = 2 * 2**20
 
 #: SMEM bytes for one row block's neighbour indices and weights (two
-#: int32/f32 arrays, double-buffered, minor dimension padded to the lane
-#: width); the chip has 1 MiB of SMEM.
-SMEM_BLOCK_BYTES = 512 * 2**10
+#: int32/f32 arrays, minor dimension padded to the lane width) and its
+#: occupied widths (one int32 a row), all double-buffered: 512 KiB for the
+#: first two and 2 KiB for the widths of at most 256 rows; the chip has
+#: 1 MiB of SMEM.
+SMEM_BLOCK_BYTES = 514 * 2**10
 
 
 def default_interpret() -> bool:
@@ -63,9 +65,10 @@ def lane_block_f(block_f: int | None, f: int, rows: int, itemsize: int = 4) -> i
 
 def row_block(block_v: int, v: int, d: int) -> int:
     """Rows per grid step: ``block_v`` rounded up to whole sublane tiles
-    and capped so the ``(rows, d)`` index and weight blocks fit
-    :data:`SMEM_BLOCK_BYTES`; a block that would cover ``v`` becomes ``v``."""
-    cap = SMEM_BLOCK_BYTES // (4 * 4 * cdiv(d, LANE) * LANE)
+    and capped so the ``(rows, d)`` index and weight blocks and the
+    ``(rows,)`` occupied widths fit :data:`SMEM_BLOCK_BYTES`; a block that
+    would cover ``v`` becomes ``v``."""
+    cap = SMEM_BLOCK_BYTES // (2 * 4 * (2 * cdiv(d, LANE) * LANE + 1))
     bv = min(cdiv(block_v, SUBLANE) * SUBLANE, max(SUBLANE, cap // SUBLANE * SUBLANE))
     return v if bv >= v else bv
 

@@ -20,7 +20,10 @@ The price of the row-block-outer grid: with more than one feature block,
 every row block streams the whole (V, F) table from HBM again, so one
 call reads (V_pad / T_V) x V x F elements of it (the ELL SpMM kernel,
 row blocks inner, reads the table once).  With a single feature block
-the block index never changes and the table is read once.
+the block index never changes and the table is read once.  The gather
+walks only each row's occupied ELL slots, so its cost per feature block
+is the row block's nonzeros plus a fixed overhead per row, not
+T_V x D; the table stream and the MXU matmul are paid in full.
 """
 from __future__ import annotations
 
@@ -32,14 +35,15 @@ from jax.experimental.pallas import tpu as pltpu
 from ..spmm.kernel import gather_rows
 
 
-def _kernel(idx_ref, wts_ref, x_ref, w_ref, o_ref, h_ref):
+def _kernel(cnt_ref, idx_ref, wts_ref, x_ref, w_ref, o_ref, h_ref):
     """out[b, :] += (sum_d wts[b,d] * x[idx[b,d], fblock]) @ w[fblock] — fused."""
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    gather_rows(idx_ref, wts_ref, x_ref, h_ref)  # the tile lives only in VMEM
+    # the tile lives only in VMEM
+    gather_rows(cnt_ref, idx_ref, wts_ref, x_ref, h_ref)
     o_ref[...] += jnp.dot(
         h_ref[...], w_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
@@ -47,6 +51,7 @@ def _kernel(idx_ref, wts_ref, x_ref, w_ref, o_ref, h_ref):
 
 
 def fused_agg_cmb_kernel(
+    counts: jax.Array,  # (V_pad,) int32, see spmm.kernel.occupied_width
     indices: jax.Array,  # (V_pad, D)
     weights: jax.Array,  # (V_pad, D)
     x: jax.Array,  # (V, F)
@@ -65,11 +70,16 @@ def fused_agg_cmb_kernel(
     assert f == f2
     bv, bf = block_v, block_f
     smem = pl.BlockSpec((bv, d), lambda i, k: (i, 0), memory_space=pltpu.SMEM)
+    # the widths as (row blocks, 1, bv): a 1-D SMEM block must match the
+    # array's HBM tiling, and a (1, bv) block of a 2-D array is not legal
+    cnt = pl.BlockSpec((None, 1, bv), lambda i, k: (i, 0, 0),
+                       memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((v_pad, g), jnp.float32),
         grid=(v_pad // bv, f // bf),
         in_specs=[
+            cnt,
             smem,
             smem,
             pl.BlockSpec((v, bf), lambda i, k: (0, k)),  # one feature block
@@ -82,4 +92,4 @@ def fused_agg_cmb_kernel(
         ),
         interpret=interpret,
         name="fused_agg_cmb",
-    )(indices, weights, x, w)
+    )(counts.reshape(v_pad // bv, 1, bv), indices, weights, x, w)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import cdiv, default_interpret, lane_block_f, row_block
+from ..spmm.kernel import occupied_width
 from .kernel import fused_agg_cmb_kernel as _raw
 from .ref import fused_ref
 
@@ -31,8 +32,8 @@ def _fused_kernel(indices, weights, x, w, band_size, block_f):
     wts = jnp.pad(weights, ((0, vp - v_pad), (0, 0)))
     xp = jnp.pad(x, ((0, 0), (0, fp - f)))
     wp = jnp.pad(w, ((0, fp - f), (0, 0)))
-    out = _raw(idx, wts, xp, wp, block_v=bv, block_f=bf,
-               interpret=default_interpret())
+    out = _raw(occupied_width(wts), idx, wts, xp, wp,
+               block_v=bv, block_f=bf, interpret=default_interpret())
     return out[:v_pad].astype(x.dtype)
 
 

@@ -9,14 +9,17 @@ the neighbor dimension is walked temporally inside the kernel
 block of the vertex table stays resident in VMEM while every row block
 gathers from it.
 
-The row block's neighbor indices and weights sit in SMEM; each output row
-accumulates its D neighbor rows, read one at a time by a dynamic
-``pl.ds`` slice of the table (the chip's vector units cannot gather by a
-vector of row indices).
+The row block's neighbor indices, weights and occupied widths sit in
+SMEM; each output row accumulates its neighbor rows, read one at a time by
+a dynamic ``pl.ds`` slice of the table (the chip's vector units cannot
+gather by a vector of row indices).
 
 The padded slots (weight 0, index 0) are the lockstep/evil-row waste the
-paper's simulator charges for — here they cost real gather steps, so the
-kernel's cost structure matches the cost model's.
+paper's simulator charges for.  The kernel does not pay it: each row walks
+only its occupied width (:func:`occupied_width`, one past its last nonzero
+weight), so a row costs its own degree plus a fixed row overhead, and an
+empty pad row costs the overhead alone.  Every skipped slot has weight 0,
+so for finite features the result is bit for bit that of the padded walk.
 """
 from __future__ import annotations
 
@@ -26,10 +29,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def gather_rows(idx_ref, wts_ref, x_ref, h_ref) -> None:
-    """h[b, :] = sum_d wts[b, d] * x[idx[b, d], :] for every row b of the
-    block, accumulated in float32."""
-    rows, ell_width = idx_ref.shape
+def occupied_width(weights: jax.Array) -> jax.Array:
+    """Per row, one past its last slot with a nonzero weight (0 for a row
+    with none): the slots :func:`gather_rows` walks.  ``(V_pad,)`` int32."""
+    slot = jnp.arange(1, weights.shape[1] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(weights != 0, slot, 0), axis=1, initial=0)
+
+
+def gather_rows(cnt_ref, idx_ref, wts_ref, x_ref, h_ref) -> None:
+    """h[b, :] = sum_{d < cnt[0, b]} wts[b, d] * x[idx[b, d], :] for every
+    row b of the block, accumulated in float32.  ``cnt_ref`` is the block's
+    ``(1, rows)`` slice of :func:`occupied_width`."""
+    rows = idx_ref.shape[0]
     width = h_ref.shape[1]
 
     def row(b, carry):
@@ -38,7 +49,7 @@ def gather_rows(idx_ref, wts_ref, x_ref, h_ref) -> None:
             return acc + wts_ref[b, d] * nbr
 
         acc = jax.lax.fori_loop(
-            0, ell_width, slot, jnp.zeros((1, width), jnp.float32)
+            0, cnt_ref[0, b], slot, jnp.zeros((1, width), jnp.float32)
         )
         h_ref[pl.ds(b, 1), :] = acc.astype(h_ref.dtype)
         return carry
@@ -46,11 +57,12 @@ def gather_rows(idx_ref, wts_ref, x_ref, h_ref) -> None:
     jax.lax.fori_loop(0, rows, row, 0)
 
 
-def _kernel(idx_ref, wts_ref, x_ref, o_ref):
-    gather_rows(idx_ref, wts_ref, x_ref, o_ref)
+def _kernel(cnt_ref, idx_ref, wts_ref, x_ref, o_ref):
+    gather_rows(cnt_ref, idx_ref, wts_ref, x_ref, o_ref)
 
 
 def spmm_ell(
+    counts: jax.Array,  # (V_pad,) int32, see occupied_width
     indices: jax.Array,  # (V_pad, D) int32
     weights: jax.Array,  # (V_pad, D) f32
     x: jax.Array,  # (V, F)
@@ -59,7 +71,8 @@ def spmm_ell(
     block_f: int,
     interpret: bool,
 ) -> jax.Array:
-    """out[v] = sum_d weights[v, d] * x[indices[v, d]]  — (V_pad, F).
+    """out[v] = sum_{d < counts[v]} weights[v, d] * x[indices[v, d]]  —
+    (V_pad, F).
 
     ``block_v`` must divide V_pad and ``block_f`` must divide F; both must
     be legal TPU block extents (see :mod:`repro.kernels.spmm.ops`)."""
@@ -67,11 +80,16 @@ def spmm_ell(
     v, f = x.shape
     bv, bf = block_v, block_f
     smem = pl.BlockSpec((bv, d), lambda j, i: (i, 0), memory_space=pltpu.SMEM)
+    # the widths as (row blocks, 1, bv): a 1-D SMEM block must match the
+    # array's HBM tiling, and a (1, bv) block of a 2-D array is not legal
+    cnt = pl.BlockSpec((None, 1, bv), lambda j, i: (i, 0, 0),
+                       memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((v_pad, f), x.dtype),
         grid=(f // bf, v_pad // bv),
         in_specs=[
+            cnt,
             smem,
             smem,
             pl.BlockSpec((v, bf), lambda j, i: (0, j)),  # full vertex table
@@ -82,4 +100,4 @@ def spmm_ell(
         ),
         interpret=interpret,
         name="spmm_ell",
-    )(indices, weights, x)
+    )(counts.reshape(v_pad // bv, 1, bv), indices, weights, x)

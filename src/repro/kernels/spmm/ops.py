@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import cdiv, default_interpret, lane_block_f, row_block
-from .kernel import spmm_ell as _raw
+from .kernel import occupied_width, spmm_ell as _raw
 from .ref import spmm_ref
 
 
@@ -30,7 +30,7 @@ def _spmm_kernel(indices, weights, x, block_v, block_f):
     idx = jnp.pad(indices, ((0, vp - v_pad), (0, 0)))
     wts = jnp.pad(weights, ((0, vp - v_pad), (0, 0)))
     xp = jnp.pad(x, ((0, 0), (0, fp - f)))
-    out = _raw(idx, wts, xp, block_v=bv, block_f=bf,
+    out = _raw(occupied_width(wts), idx, wts, xp, block_v=bv, block_f=bf,
                interpret=default_interpret())
     return out[:v_pad, :f]
 

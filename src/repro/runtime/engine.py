@@ -197,6 +197,11 @@ class EngineStats:
     partition_wall_s: float = 0.0  # wall spent inside the partitioned lane
     partition_plans: dict = field(default_factory=dict)  # plan kind -> count
     n_compiles: int = 0  # backend compiles taken by executions and primes
+    #: Σ V_pad x D of the padded ELL over bound micro-batches, and Σ of
+    #: their real nonzeros: ``ell_slots_used / ell_slots`` is the share of
+    #: slots the aggregation kernels walk (they skip each row's padding)
+    ell_slots: int = 0
+    ell_slots_used: int = 0
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -428,6 +433,8 @@ class InferenceEngine:
         self._search_s = 0.0  # mapper search + Program packaging
         self._trace_s = 0.0  # wall of executions that took new XLA traces
         self._n_compiles = 0  # backend compiles taken by those executions
+        self._ell_slots = 0  # padded-ELL slots of bound micro-batches
+        self._ell_slots_used = 0  # their real nonzeros
         self._n_searches = 0  # mapper searches actually run
         self._status_counts = {s: 0 for s in
                                (STATUS_OK, STATUS_REJECTED, STATUS_FAILED,
@@ -1533,6 +1540,9 @@ class InferenceEngine:
         prog = self._program_for(batch, tier)
         with TraceAnnotation("repro.bind", batch=batch_id):
             bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
+        self._ell_slots += bound.adj.indices.size
+        # pad rows hold one zero-weight self-loop each, which no kernel walks
+        self._ell_slots_used += np.count_nonzero(batch.graph.values)
         corrupt = None
         if self.injector is not None:
             corrupt = self.injector.on_run(
@@ -1629,4 +1639,6 @@ class InferenceEngine:
             partition_wall_s=self._partition_wall_s,
             partition_plans=dict(self._partition_plans),
             n_compiles=self._n_compiles,
+            ell_slots=self._ell_slots,
+            ell_slots_used=self._ell_slots_used,
         )

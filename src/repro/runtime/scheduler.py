@@ -114,6 +114,9 @@ class AsyncEngineStats:
     #: Σ (worker pops the group - dispatch) over every dispatched group
     inbox_wait_s: float = 0.0
     n_groups: int = 0
+    #: Σ over workers of EngineStats.ell_slots / ell_slots_used
+    ell_slots: int = 0
+    ell_slots_used: int = 0
     errors: dict = field(default_factory=dict)
     placement: dict = field(default_factory=dict)  # "VxD" -> [device labels]
     per_device: dict = field(default_factory=dict)  # label -> EngineStats dict
@@ -785,7 +788,7 @@ class AsyncEngine:
                 n_groups=self._n_groups,
             )
         per_device: dict[str, EngineStats] = {}
-        n_served = 0
+        n_served = ell_slots = ell_slots_used = 0
         for w in self.workers:
             s = w.engine.stats()
             per_device[str(w.device)] = s
@@ -795,6 +798,8 @@ class AsyncEngine:
             status[STATUS_FAILED] += s.n_failed
             status[STATUS_DEGRADED] += s.n_degraded
             n_served += s.n_ok + s.n_degraded
+            ell_slots += s.ell_slots
+            ell_slots_used += s.ell_slots_used
             for code, n in s.errors.items():
                 errors[code] = errors.get(code, 0) + n
         lat_ms = np.asarray(lat, dtype=np.float64) * 1e3
@@ -813,6 +818,8 @@ class AsyncEngine:
             n_flushes_deadline=n_deadline,
             max_inflight=max_inflight,
             **waits,
+            ell_slots=ell_slots,
+            ell_slots_used=ell_slots_used,
             errors=errors,
             placement=self.placement(),
             per_device={k: v.as_dict() for k, v in per_device.items()},

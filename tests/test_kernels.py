@@ -14,6 +14,9 @@ from repro.kernels.gemm_dataflow import DATAFLOWS, gemm_ref
 from repro.kernels.gemm_dataflow.ops import gemm
 from repro.kernels.common import default_interpret, lane_block_f, row_block
 from repro.kernels.spmm import spmm, spmm_ref
+from repro.kernels.spmm.kernel import occupied_width
+import repro.kernels.fused_agg_cmb.ops as fused_ops
+import repro.kernels.spmm.ops as spmm_ops
 
 RNG = np.random.default_rng(42)
 
@@ -72,6 +75,45 @@ def random_ell(v, max_deg, seed=0):
     return jnp.asarray(idx), jnp.asarray(wts)
 
 
+def skewed_ell(v, v_pad, hub, d, seed=0):
+    """A power-law-like bucket: one hub row of ``hub`` slots, every other
+    real row 1-3, rows ``v:v_pad`` empty (bucket padding), ELL width ``d``
+    (``hub == d``: the hub sets the width; ``hub < d``: the bucket is
+    wider than any row)."""
+    rng = np.random.default_rng(seed)
+    deg = rng.integers(1, 4, size=v_pad)
+    deg[v:], deg[v // 2] = 0, hub
+    idx = np.zeros((v_pad, d), np.int32)
+    wts = np.zeros((v_pad, d), np.float32)
+    for r, k in enumerate(deg):
+        idx[r, :k] = rng.integers(0, v, k)
+        wts[r, :k] = rng.normal(size=k)
+    return jnp.asarray(idx), jnp.asarray(wts)
+
+
+def make_ell(v, deg, seed):
+    """``deg`` an int: :func:`random_ell`; a ``(v_pad, hub, d)`` tuple:
+    :func:`skewed_ell`."""
+    if isinstance(deg, tuple):
+        return skewed_ell(v, *deg, seed=seed)
+    return random_ell(v, deg, seed=seed)
+
+
+#: skewed buckets as ``deg`` cases of the oracle tests: the hub at the
+#: full ELL width, and a bucket width past the max degree; both with pad
+#: rows and two row blocks of 32 or more
+SKEWED_CASES = [
+    pytest.param((80, 40, 40), id="skewed-hub-full-width"),
+    pytest.param((128, 12, 64), id="skewed-pad_to-wide"),
+]
+
+
+def full_width(weights):
+    """Every row's slot count at the full ELL width: the padded walk the
+    kernels made before they stopped at each row's occupied width."""
+    return jnp.full(weights.shape[:1], weights.shape[1], jnp.int32)
+
+
 def fixed_ell(v, d, seed=0):
     """ELL rows of exactly ``d`` random slots (D need not be a multiple
     of the 8-row sublane tile)."""
@@ -92,15 +134,48 @@ TPU_BLOCK_CASES = [
 TPU_BLOCK_IDS = ["f3703", "f300-block_f8", "f16-block_f8", "f130-d13"]
 
 
+class TestOccupiedWalk:
+    def test_one_past_the_last_nonzero(self):
+        wts = jnp.asarray([[0.5, 0.0, 2.0, 0.0],  # interior zero walked
+                           [0.0, 0.0, 0.0, 0.0],  # empty: no slot
+                           [1.0, 1.0, 1.0, -1.0],  # full width
+                           [0.0, np.nan, 0.0, 0.0]],  # NaN is not zero
+                          jnp.float32)
+        assert occupied_width(wts).tolist() == [3, 0, 4, 2]
+        assert occupied_width(wts).dtype == jnp.int32
+
+    @pytest.mark.parametrize("kernel", ["spmm", "fused_agg_cmb"])
+    def test_padded_slots_are_not_read(self, kernel):
+        """Padded slots point at row 0; with row 0 infinite and no real slot
+        pointing at it, the padded walk reads inf * 0 = NaN, the occupied
+        walk never reads it."""
+        idx, wts = skewed_ell(40, 64, 12, 16, seed=5)
+        idx = jnp.where(wts != 0, jnp.maximum(idx, 1), 0)
+        x = rand((40, 24)).at[0].set(jnp.inf)
+        if kernel == "spmm":
+            out = spmm(idx, wts, x, block_v=16, block_f=128)
+        else:
+            out = fused_agg_cmb(idx, wts, x, rand((24, 8)), band_size=16)
+        assert np.isfinite(np.asarray(out)).all()
+
+
 class TestSpmm:
-    @pytest.mark.parametrize("v,f,deg", [(64, 32, 4), (200, 96, 8), (17, 5, 3)])
-    def test_matches_oracle(self, v, f, deg):
-        idx, wts = random_ell(v, deg, seed=v)
+    @pytest.mark.parametrize(
+        "v,f,deg",
+        [(64, 32, 4), (200, 96, 8), (17, 5, 3),
+         *(pytest.param(50, 40, c.values[0], id=c.id) for c in SKEWED_CASES)],
+    )
+    def test_matches_oracle(self, monkeypatch, v, f, deg):
+        idx, wts = make_ell(v, deg, seed=v)
         x = rand((v, f))
         out = spmm(idx, wts, x, block_v=32, block_f=32)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(spmm_ref(idx, wts, x)), rtol=1e-4, atol=1e-5
         )
+        monkeypatch.setattr(spmm_ops, "occupied_width", full_width)
+        padded = jax.jit(spmm_ops._spmm_kernel, static_argnums=(3, 4))(
+            idx, wts, x, 32, 32)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(padded))
 
     def test_matches_dense_spmm(self):
         g = from_edges(50, np.arange(49), np.arange(1, 50))
@@ -143,14 +218,23 @@ class TestSpmm:
 class TestFusedAggCmb:
     """The SP-Optimized kernel: fused == aggregate-then-GEMM."""
 
-    @pytest.mark.parametrize("v,f,g,deg", [(64, 32, 16, 4), (130, 48, 8, 6)])
-    def test_matches_oracle(self, v, f, g, deg):
-        idx, wts = random_ell(v, deg, seed=v)
+    @pytest.mark.parametrize(
+        "v,f,g,deg",
+        [(64, 32, 16, 4), (130, 48, 8, 6),
+         *(pytest.param(50, 300, 8, c.values[0], id=c.id)
+           for c in SKEWED_CASES)],
+    )
+    def test_matches_oracle(self, monkeypatch, v, f, g, deg):
+        idx, wts = make_ell(v, deg, seed=v)
         x, w = rand((v, f)), rand((f, g))
         out = fused_agg_cmb(idx, wts, x, w, band_size=32)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(fused_ref(idx, wts, x, w)), rtol=1e-4, atol=1e-4
         )
+        monkeypatch.setattr(fused_ops, "occupied_width", full_width)
+        padded = jax.jit(fused_ops._fused_kernel, static_argnums=(4, 5))(
+            idx, wts, x, w, 32, None)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(padded))
 
     def test_fused_equals_two_phase(self):
         v, f, g, deg = 96, 40, 12, 5

@@ -9,7 +9,7 @@ import pytest
 import repro
 from repro.core import GNNLayerWorkload
 from repro.core.schedule import ModelSchedule
-from repro.gnn.layers import segment_readout
+from repro.gnn.layers import EllAdjacency, segment_readout
 from repro.graphs import BucketPolicy, assemble, from_edges
 from repro.runtime.engine import InferenceEngine, ProgramCache, Request
 
@@ -325,6 +325,27 @@ class TestInferenceEngine:
             "a (32,4)-bucket batch reused the (16,4)x2 Program"
         )
 
+    def test_ell_slot_counters_read_the_bound_batch(self):
+        """``ell_slots_used / ell_slots`` is the batch's real nonzeros over
+        the bound padded ELL's V_pad x D: three 12-node rings (36 nonzeros
+        each with their self-loops) in a (16, 4) bucket of 4 slots, rows
+        grouped to the schedule's ELL block.  Pad rows' zero-weight
+        self-loops are not counted."""
+        eng = self.engine()
+        reqs = [make_request(12, seed=i, rid=i) for i in range(3)]
+        eng.submit(reqs)
+        batch = assemble([r.graph for r in reqs], self.POL)
+        v_pad, d = EllAdjacency.from_schedule(
+            batch.graph, SCHEDULE, pad_to=batch.d_bucket
+        ).indices.shape
+        assert batch.n_pad == 64 - 36 and d == 4
+        st = eng.stats()
+        assert (st.ell_slots, st.ell_slots_used) == (v_pad * d, 3 * 36)
+        eng.submit(reqs[:1])  # a 1-slot tail batch of its own shape
+        st1 = eng.stats()
+        assert st1.ell_slots_used == st.ell_slots_used + 36
+        assert st1.ell_slots > st.ell_slots
+
     def test_mapper_search_runs_once_per_bucket(self):
         """Without a pinned schedule, the engine searches on a bucket's
         first batch and reuses the schedule for later slot variants."""
@@ -334,6 +355,32 @@ class TestInferenceEngine:
         eng.submit(reqs)  # 4-slot batch + 1-slot tail: two cache keys
         assert eng.cache.misses == 2
         assert len(eng._schedules) == 1  # but one mapper search
+
+
+def test_async_stats_sum_ell_slots_over_workers():
+    """Two workers (on one device) serve three buckets, one request each,
+    so every batch is the sync engine's whatever the windows do; the front
+    end's counters are the sum of the workers' and equal the sync
+    engine's."""
+    from repro.runtime import AsyncEngine
+
+    pol = TestInferenceEngine.POL
+    reqs = [make_request(12, seed=1, rid=1),
+            make_request(20, seed=9, rid=9, chords=4),
+            make_request(40, seed=5, rid=10)]
+    sync = InferenceEngine(DIMS, policy=pol, schedule=SCHEDULE)
+    params = sync.init(jax.random.PRNGKey(0))
+    sync.submit(reqs)
+    dev = jax.devices()[0]
+    with AsyncEngine(DIMS, params, devices=[dev, dev], window_ms=5.0,
+                     policy=pol, schedule=SCHEDULE) as a:
+        assert all(r.status == "ok" for r in a.submit(reqs))
+    per = [w.engine.stats() for w in a.workers]
+    assert all(s.ell_slots_used > 0 for s in per), "a worker served nothing"
+    st, want = a.stats(), sync.stats()
+    assert st.ell_slots == sum(s.ell_slots for s in per) == want.ell_slots
+    assert (st.ell_slots_used == sum(s.ell_slots_used for s in per)
+            == want.ell_slots_used)
 
 
 class TestPartitionAwareAdmission:

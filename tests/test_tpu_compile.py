@@ -3,9 +3,13 @@
 Nothing runs here.  The chip is described (``jax.experimental.topologies``)
 and each kernel wrapper is lowered and compiled for one of its devices at
 the widths the serving path uses: the citeseer bucket of the paper's
-2-layer GCN (4096 rows, ELL width 64, 3703 -> 16 -> 6), a batched mutag
-bucket (64 slots of 32 nodes, 28 features), and the mapper's narrow
-``block_f`` of 8; and a training step's gradient through each kernel.
+2-layer GCN (4096 rows, ELL width 64, 3703 -> 16 -> 6), the whole-citeseer
+bucket whose hub sets ELL width 128, an IMDB-BINARY bucket (64 slots of 32
+nodes, ELL width 32, 136 -> 16 -> 2), a batched mutag bucket (64 slots of
+32 nodes, 28 features), and the mapper's narrow ``block_f`` of 8; and a
+training step's gradient through each kernel.  Every case carries the
+kernels' per-row occupied widths (an SMEM block walked by a dynamic trip
+count), so a form of them Mosaic refuses fails here.
 The compiler refuses what interpret mode accepts
 (unaligned blocks, vector-indexed gathers, more VMEM than a kernel may
 use), so these tests guard the chip path without a chip.
@@ -21,6 +25,8 @@ import repro.kernels.fused_agg_cmb.ops as fused_ops
 import repro.kernels.spmm.ops as spmm_ops
 
 CITESEER = dict(rows=4096, d=64)
+CITESEER_FULL = dict(rows=4096, d=128)
+IMDB = dict(rows=32 * 64, d=32)
 MUTAG = dict(rows=32 * 64, d=8)
 
 
@@ -82,8 +88,12 @@ def _ell(for_chip, rows, d):
         (CITESEER, 16, 128, 128),  # layer 2, and CA order's aggregation
         (MUTAG, 28, 128, 128),
         (CITESEER, 3703, 64, 8),  # mapper-emitted Vs(64)Fs(8)
+        (CITESEER_FULL, 3703, 128, 128),
+        (IMDB, 136, 128, 128),  # seq layer 1 of the imdb cells
+        (IMDB, 16, 128, 128),
     ],
-    ids=["citeseer-3703", "citeseer-16", "mutag-28", "citeseer-block_f8"],
+    ids=["citeseer-3703", "citeseer-16", "mutag-28", "citeseer-block_f8",
+         "citeseer-full-3703", "imdb-136", "imdb-16"],
 )
 def test_spmm_compiles_for_v5e(for_chip, bucket, f, block_v, block_f):
     idx, wts = _ell(for_chip, **bucket)
@@ -101,8 +111,12 @@ def test_spmm_compiles_for_v5e(for_chip, bucket, f, block_v, block_f):
         (CITESEER, 16, 6, 128, None),  # layer 2
         (MUTAG, 28, 16, 128, None),
         (CITESEER, 3703, 16, 64, 8),  # mapper-emitted Vs(64)Fs(8)
+        (CITESEER_FULL, 3703, 16, 128, None),  # the citeseer-full bucket
+        (CITESEER_FULL, 16, 6, 128, None),
+        (IMDB, 136, 16, 128, None),
     ],
-    ids=["citeseer-3703x16", "citeseer-16x6", "mutag-28x16", "citeseer-block_f8"],
+    ids=["citeseer-3703x16", "citeseer-16x6", "mutag-28x16", "citeseer-block_f8",
+         "citeseer-full-3703x16", "citeseer-full-16x6", "imdb-136x16"],
 )
 def test_fused_agg_cmb_compiles_for_v5e(for_chip, bucket, f, g, band, block_f):
     idx, wts = _ell(for_chip, **bucket)
