@@ -49,7 +49,7 @@ from .core.simulator import (
     TransitionStats,
     simulate_model,
 )
-from .gnn.layers import LAYER_FNS, EllAdjacency, init_layer
+from .gnn.layers import DEFAULT_HEADS, LAYER_FNS, EllAdjacency, init_layers
 from .gnn.model import GNNConfig, forward_layers, masked_xent_loss
 from .graphs.csr import CSRGraph
 from .kernels.common import resolve_use_pallas
@@ -168,11 +168,13 @@ class Program:
 
     schedule: ModelSchedule
     hw: AcceleratorConfig = DEFAULT_ACCEL
-    kind: str = "gcn"  # gcn | sage | gin
+    kind: str = "gcn"  # gcn | sage | gin | gat
     objective: str = "cycles"
     use_pallas: bool = False
     fingerprint: dict = field(default_factory=dict)
     stats: ModelStats | None = field(default=None, compare=False, repr=False)
+    #: attention heads of every layer (``kind="gat"`` only)
+    heads: int = DEFAULT_HEADS
     #: runtime adjacency binding (set by compile(graph=...) / bind()); not
     #: part of the artifact and never serialized.
     adj: EllAdjacency | None = field(default=None, compare=False, repr=False)
@@ -253,11 +255,7 @@ class Program:
     # -- execution ----------------------------------------------------------
     def init(self, rng: jax.Array):
         """Initialize layer parameters matching the schedule's shapes."""
-        keys = jax.random.split(rng, self.n_layers)
-        return [
-            init_layer(self.kind, k, fi, fo)
-            for k, (fi, fo) in zip(keys, self.dims)
-        ]
+        return init_layers(self.kind, rng, self.dims, heads=self.heads)
 
     def _executable(
         self,
@@ -466,6 +464,8 @@ class Program:
             "schedule": json.loads(self.schedule.to_json(indent=None)),
             "stats": None if self.stats is None else _stats_to_dict(self.stats),
         }
+        if self.kind == "gat":
+            payload["heads"] = self.heads
         return json.dumps(payload, indent=2, sort_keys=True) + "\n"
 
     @classmethod
@@ -485,6 +485,7 @@ class Program:
             use_pallas=d["use_pallas"],
             fingerprint=d["fingerprint"],
             stats=stats,
+            heads=d.get("heads", DEFAULT_HEADS),
         )
 
     def save(self, path) -> Path:
@@ -549,6 +550,38 @@ class Program:
 # ---------------------------------------------------------------------------
 
 
+def layer_workloads(
+    nnz: np.ndarray, dims, *, kind: str = "gcn", heads: int = DEFAULT_HEADS
+) -> list[GNNLayerWorkload]:
+    """One workload per ``(f_in, f_out)`` layer over a degree vector; a
+    ``gat`` model's carry its ``heads``, its last layer averaging them."""
+    h = heads if kind == "gat" else 0
+    return [
+        GNNLayerWorkload(nnz, fi, fo, name=f"layer{i}", heads=h,
+                         concat=i < len(dims) - 1)
+        for i, (fi, fo) in enumerate(dims)
+    ]
+
+
+def _check_heads(kind: str, workloads) -> int:
+    """The head count a ``kind`` Program gets from its workloads: every
+    layer of a ``gat`` model carries the same heads, no other kind's any."""
+    heads = {wl.heads for wl in workloads}
+    if kind == "gat":
+        if len(heads) != 1 or 0 in heads:
+            raise ValueError(
+                f"a gat Program needs the same nonzero heads on every layer "
+                f"workload, got {sorted(heads)}"
+            )
+        return heads.pop()
+    if heads != {0}:
+        raise ValueError(
+            f"only gat layers have attention heads; kind {kind!r} got "
+            f"workloads with heads {sorted(heads)}"
+        )
+    return DEFAULT_HEADS
+
+
 def _resolve_workloads(
     target, graph: CSRGraph | None
 ) -> tuple[list[GNNLayerWorkload], GNNConfig | None]:
@@ -560,11 +593,9 @@ def _resolve_workloads(
                 "compiling from a GNNConfig needs graph=... (the workload's "
                 "degree vector comes from the graph)"
             )
-        wls = [
-            GNNLayerWorkload(graph.nnz, fi, fo, name=f"layer{i}")
-            for i, (fi, fo) in enumerate(target.dims)
-        ]
-        return wls, target
+        return layer_workloads(
+            graph.nnz, target.dims, kind=target.kind, heads=target.heads
+        ), target
     wls = list(target)
     if not wls:
         raise ValueError("need at least one layer workload")
@@ -692,6 +723,7 @@ def compile(
     if use_pallas is None and cfg is not None:
         use_pallas = cfg.use_pallas
     use_pallas = resolve_use_pallas(use_pallas)
+    heads = _check_heads(kind, workloads)
 
     if schedule is not None:
         want = [(wl.f_in, wl.g_out) for wl in workloads]
@@ -764,6 +796,7 @@ def compile(
         use_pallas=use_pallas,
         fingerprint=workload_fingerprint(workloads),
         stats=stats,
+        heads=heads,
         codesign=codesign_log,
     )
     return prog.bind(graph) if graph is not None else prog

@@ -32,12 +32,21 @@ from .taxonomy import (
 
 @dataclass(frozen=True)
 class GNNLayerWorkload:
-    """One GCN layer: AX W (AC) or A (XW) (CA) over a CSR graph."""
+    """One GNN layer: AX W (AC) or A (XW) (CA) over a CSR graph.
+
+    ``heads`` > 0 makes it an attention (GAT) layer: its edge weights are
+    computed from the combined features, ``heads`` per edge, so it runs
+    CA only.  Its GEMM and aggregation are ``width`` = H*F' wide: the
+    output ``g_out`` itself when the heads are concatenated, H times it
+    when they are averaged (``concat=False``, a model's last layer).
+    """
 
     nnz: np.ndarray  # per-vertex neighbor count (self-loops included)
     f_in: int
     g_out: int
     name: str = ""
+    heads: int = 0  # 0 = fixed (adjacency) edge weights
+    concat: bool = True
 
     @property
     def v(self) -> int:
@@ -47,11 +56,52 @@ class GNNLayerWorkload:
     def e(self) -> int:
         return int(self.nnz.sum())
 
+    @property
+    def width(self) -> int:
+        """Columns the combination computes and the aggregation carries."""
+        if self.heads and not self.concat:
+            return self.heads * self.g_out
+        return self.g_out
+
+    def fixed_weight(self) -> "GNNLayerWorkload":
+        """The two-phase part of the layer as a fixed-weight layer of its
+        computed width (what :func:`attention_cost` leaves out)."""
+        if not self.heads:
+            return self
+        return GNNLayerWorkload(self.nnz, self.f_in, self.width, self.name)
+
     def macs(self, order: PhaseOrder) -> tuple[int, int]:
         """(aggregation MACs, combination MACs)."""
-        cmb = self.v * self.f_in * self.g_out
-        agg = self.e * (self.f_in if order == PhaseOrder.AC else self.g_out)
+        cmb = self.v * self.f_in * self.width
+        agg = self.e * (self.f_in if order == PhaseOrder.AC else self.width)
         return agg, cmb
+
+
+#: operations per (edge, head) of an attention layer: the score's add
+#: and LeakyReLU, the online softmax's max, two exps and rescale, its sum
+ATTN_OPS_PER_EDGE_HEAD = 8
+
+
+def attention_cost(wl: GNNLayerWorkload, hw: AcceleratorConfig) -> PhaseCost:
+    """The work an attention layer adds to its two phases: the two score
+    projections (2·V·H·F' MACs) and, per (edge, head), the score, the
+    softmax and the normalisation (:data:`ATTN_OPS_PER_EDGE_HEAD`
+    operations), on every PE; the neighbour's score is one more GB read
+    per (edge, head), and each node writes the H scores its neighbours
+    read.  Zero for a fixed-weight layer."""
+    if not wl.heads:
+        return PhaseCost(cycles=0.0, macs=0.0)
+    proj = 2.0 * wl.v * wl.width
+    edge = float(ATTN_OPS_PER_EDGE_HEAD) * wl.e * wl.heads
+    ops = proj + edge
+    return PhaseCost(
+        cycles=ops / hw.n_pes,
+        macs=ops,
+        gb_reads={"att": float(wl.e * wl.heads)},
+        gb_writes={"att": float(wl.v * wl.heads)},
+        rf_accesses=2.0 * ops,
+        spatial_util=1.0,
+    )
 
 
 @dataclass

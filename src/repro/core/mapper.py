@@ -32,6 +32,7 @@ from .simulator import (
     RunStats,
     _GroupSpec,
     _eval_candidates,
+    attention_legal,
     expand_hw_columns,
     simulate,
     simulate_batch,
@@ -491,6 +492,23 @@ TABLE5_NAMES = (
 )
 
 
+#: the skeletons an attention layer is offered (combination first, no PP)
+ATTENTION_NAMES = ("Seq-CA-Nt", "Seq-CA-Ns")
+
+
+def _names_for(wl: GNNLayerWorkload, names: tuple[str, ...]) -> tuple[str, ...]:
+    """The skeletons of ``names`` a workload can run: all of them for a
+    fixed-weight layer; for an attention layer the CA, non-PP ones, or
+    :data:`ATTENTION_NAMES` when there are none."""
+    if not wl.heads:
+        return names
+    legal = tuple(
+        n for n in names
+        if attention_legal(named_skeleton(n).inter, named_skeleton(n).order)
+    )
+    return legal or ATTENTION_NAMES
+
+
 def search_dataflows(
     wl: GNNLayerWorkload,
     hw: AcceleratorConfig = DEFAULT_ACCEL,
@@ -506,9 +524,12 @@ def search_dataflows(
     workload-adaptive dataflow choice the paper argues flexible accelerators
     enable.  The :class:`TileStats` cache is shared across all skeletons, so
     the whole sweep costs one O(V log V) ladder build plus numpy grid
-    math."""
+    math.  An attention workload (``wl.heads`` > 0) is offered only the
+    CA, non-PP skeletons among ``names``, or :data:`ATTENTION_NAMES` when
+    there are none."""
     get_objective(objective)  # fail fast on unknown names, listing valid ones
     ts = tile_stats if tile_stats is not None else TileStats(wl.nnz)
+    names = _names_for(wl, names)
     out: list[MappingResult] = []
     for n in names:
         try:
@@ -905,7 +926,7 @@ def _grid_best_per_point(
         sel = np.flatnonzero(cols["n_pes"] == npes)
         budget_hw = replace(grid.base, n_pes=int(npes))
         sub_cols = {k: c[sel] for k, c in cols.items()}
-        for name in names:
+        for name in _names_for(wl, names):
             skeleton = named_skeleton(name)
             cand = _candidate_grid(skeleton, wl, budget_hw, pe_splits, max_evals)
             if not cand or len(cand["t_v_a"]) == 0:

@@ -15,7 +15,7 @@ Collab case).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import TYPE_CHECKING
 
 import numpy as np
@@ -25,6 +25,7 @@ from .cost_model import (
     PhaseCost,
     TileStats,
     aggregation_cost,
+    attention_cost,
     combination_cost,
     pipelined_elements,
     table3_buffering,
@@ -182,13 +183,52 @@ def _pp_chunk_times(
     return a, c
 
 
+def attention_legal(inter: InterPhase, order: PhaseOrder) -> bool:
+    """An attention layer needs z = X W before any score: combination
+    first (CA), and no PP (the scores of a row need all of its z)."""
+    return order == PhaseOrder.CA and inter != InterPhase.PP
+
+
+def _with_attention(
+    st: RunStats, wl: GNNLayerWorkload, hw: AcceleratorConfig
+) -> RunStats:
+    """``st`` (the layer's two phases) plus its attention work."""
+    att = attention_cost(wl, hw)
+    gb = att.gb_total
+    breakdown = dict(st.energy_breakdown)
+    breakdown["gb_att"] = gb * hw.gb_energy_pj
+    breakdown["rf"] = breakdown.get("rf", 0.0) + att.rf_accesses * hw.rf_energy_pj
+    cycles = st.cycles + att.cycles
+    macs = st.macs + att.macs
+    return replace(
+        st,
+        cycles=float(cycles),
+        energy_pj=float(sum(breakdown.values())),
+        energy_breakdown=breakdown,
+        gb_accesses={**st.gb_accesses, "att": gb},
+        rf_accesses=st.rf_accesses + att.rf_accesses,
+        macs=float(macs),
+        pe_utilization=float(min(macs / max(cycles * hw.n_pes, 1e-9), 1.0)),
+    )
+
+
 def simulate(
     df: GNNDataflow,
     wl: GNNLayerWorkload,
     hw: AcceleratorConfig = DEFAULT_ACCEL,
 ) -> RunStats:
-    """Simulate one GNN layer under a complete dataflow description."""
+    """Simulate one GNN layer under a complete dataflow description.  An
+    attention layer (``wl.heads`` > 0) is its two phases at the computed
+    width plus :func:`~repro.core.cost_model.attention_cost`, and raises
+    ``ValueError`` under an AC or PP dataflow."""
     df.validate()
+    if wl.heads:
+        if not attention_legal(df.inter, df.order):
+            raise ValueError(
+                f"an attention layer ({wl.heads} heads) runs CA and not PP; "
+                f"got {df.inter.value} {df.order.value}"
+            )
+        return _with_attention(simulate(df, wl.fixed_weight(), hw), wl, hw)
     if df.inter == InterPhase.PP:
         pe_first = max(1, int(round(hw.n_pes * df.pe_split)))
         pe_second = max(1, hw.n_pes - pe_first)
@@ -527,8 +567,20 @@ def _eval_candidates(
     = unconstrained) columns override the scalar ``hw`` values per
     candidate, so one call can price a dataflow x hardware grid (``hw``
     still supplies the shared energy constants).  Requires a non-empty
-    workload (V > 0, E > 0).
+    workload (V > 0, E > 0).  An attention workload is priced as in
+    :func:`simulate`, and its AC and PP candidates are illegal.
     """
+    if wl.heads:
+        res = _eval_candidates(spec, cand, wl.fixed_weight(), hw, ts)
+        att = attention_cost(wl, hw)
+        n_pes = np.asarray(cand.get("n_pes", hw.n_pes), dtype=np.float64)
+        res["cycles"] = res["cycles"] + att.macs / n_pes
+        res["energy_pj"] = res["energy_pj"] + (
+            att.gb_total * hw.gb_energy_pj + att.rf_accesses * hw.rf_energy_pj
+        )
+        res["macs"] = res["macs"] + att.macs
+        res["legal"] = res["legal"] & attention_legal(spec.inter, spec.order)
+        return res
     t_v_a = np.asarray(cand["t_v_a"], dtype=np.int64)
     t_n = np.asarray(cand["t_n"], dtype=np.int64)
     t_f_a = np.asarray(cand["t_f_a"], dtype=np.int64)

@@ -624,6 +624,15 @@ SKELETONS: dict[str, DataflowSkeleton] = {
         "PP-Ns-Vsh", InterPhase.PP, PhaseOrder.AC,
         _sk("VFN", "xxs"), _sk("VGF", "hxx"),
     ),
+    # Seq_CA(VxFxNt, VxGxFx) — combination first, temporal aggregation:
+    # the only orders an attention (GAT) layer runs, z = X W being needed
+    # before any edge score
+    "Seq-CA-Nt": DataflowSkeleton(
+        "Seq-CA-Nt", InterPhase.SEQ, PhaseOrder.CA, _sk("VFN", "xxt"), _sk("VGF", "xxx")
+    ),
+    "Seq-CA-Ns": DataflowSkeleton(
+        "Seq-CA-Ns", InterPhase.SEQ, PhaseOrder.CA, _sk("VFN", "xxs"), _sk("VGF", "xxx")
+    ),
     # HyGCN: PP_AC(VxFsNt, VsGsFt)
     "HyGCN": DataflowSkeleton(
         "HyGCN", InterPhase.PP, PhaseOrder.AC,

@@ -1,11 +1,14 @@
 from .layers import (
+    DEFAULT_HEADS,
     EllAdjacency,
     LAYER_FNS,
     POLICIES,
     aggregate_full,
+    gat_layer,
     gcn_layer,
     gin_layer,
     init_layer,
+    init_layers,
     multiphase_matmul,
     sage_layer,
     segment_readout,

@@ -208,6 +208,50 @@ def _pp(adj, x, w, spec, mesh):
 _SPEC_KNOBS = ("policy", "order", "band_size", "block_f", "use_pallas")
 
 
+def _exec_spec(
+    spec: ExecSpec | None,
+    policy: str | None,
+    order: str | None,
+    band_size: int | None,
+    block_f: int | None,
+    use_pallas: bool | None,
+    default_policy: str = "sp_opt",
+    default_order: str = "AC",
+) -> ExecSpec:
+    """The one ExecSpec a layer runs under: ``spec`` when given (an
+    explicit knob that disagrees with it raises :class:`ValueError`), else
+    one built from the knobs (defaults: ``default_policy`` /
+    ``default_order`` / band 128)."""
+    if spec is not None:
+        given = dict(
+            policy=policy,
+            order=order,
+            band_size=band_size,
+            block_f=block_f,
+            use_pallas=use_pallas,
+        )
+        conflicts = {
+            k: v
+            for k, v in given.items()
+            if v is not None and v != getattr(spec, k)
+        }
+        if conflicts:
+            raise ValueError(
+                f"got an ExecSpec plus conflicting explicit "
+                f"kwargs {conflicts}; the spec has "
+                f"{ {k: getattr(spec, k) for k in conflicts} } — pass one or "
+                f"the other"
+            )
+        return spec
+    return ExecSpec(
+        policy=policy if policy is not None else default_policy,
+        order=order if order is not None else default_order,
+        band_size=band_size if band_size is not None else 128,
+        block_f=block_f,
+        use_pallas=bool(use_pallas),
+    )
+
+
 def multiphase_matmul(
     adj: EllAdjacency,
     x: jax.Array,
@@ -233,34 +277,7 @@ def multiphase_matmul(
     (defaults: ``sp_opt`` / ``AC`` / band 128), so both entry styles
     dispatch through the same kernel registry.
     """
-    if spec is not None:
-        given = dict(
-            policy=policy,
-            order=order,
-            band_size=band_size,
-            block_f=block_f,
-            use_pallas=use_pallas,
-        )
-        conflicts = {
-            k: v
-            for k, v in given.items()
-            if v is not None and v != getattr(spec, k)
-        }
-        if conflicts:
-            raise ValueError(
-                f"multiphase_matmul got an ExecSpec plus conflicting explicit "
-                f"kwargs {conflicts}; the spec has "
-                f"{ {k: getattr(spec, k) for k in conflicts} } — pass one or "
-                f"the other"
-            )
-    else:
-        spec = ExecSpec(
-            policy=policy if policy is not None else "sp_opt",
-            order=order if order is not None else "AC",
-            band_size=band_size if band_size is not None else 128,
-            block_f=block_f,
-            use_pallas=bool(use_pallas),
-        )
+    spec = _exec_spec(spec, policy, order, band_size, block_f, use_pallas)
     kernel = lookup_kernel(spec.policy, spec.order, spec.use_pallas)
     return kernel(adj, x, w, spec, mesh)
 
@@ -342,10 +359,64 @@ def gin_layer(params, adj, x, *, policy=None, order=None, **kw):
     return jax.nn.relu(h @ params["w2"] + params["b2"])
 
 
-LAYER_FNS = {"gcn": gcn_layer, "sage": sage_layer, "gin": gin_layer}
+def gat_layer(params, adj, x, *, policy=None, order=None, spec=None,
+              band_size=None, use_pallas=None, mesh=None, block_f=None,
+              last=False):
+    """GAT (Velickovic et al., arXiv:1710.10903): z = X W with the heads
+    side by side, per head h and row i
+
+        alpha_ij = softmax_j LeakyReLU_0.2(z_i a_self[h] + z_j a_nbr[h])
+
+    over the row's slots (self-loop included; the adjacency's weights act
+    only as the edge mask), and out_i = sum_j alpha_ij z_j per head.  A
+    hidden layer concatenates the heads and applies ELU, the last averages
+    them (logits).  The combination runs first, since the scores need z:
+    only a CA schedule that is not ``pp`` runs it.  The aggregation is the
+    :mod:`repro.kernels.gat_agg` kernel with ``spec.use_pallas``, its jnp
+    oracle otherwise; a row with no slot (batch padding) gives 0.  The
+    knobs default to seq/CA.
+    """
+    spec = _exec_spec(spec, policy, order, band_size, block_f, use_pallas,
+                      default_policy="seq", default_order="CA")
+    if spec.order != "CA" or spec.policy == "pp":
+        raise ValueError(
+            f"a gat layer runs combination first (CA) and has no pp path; "
+            f"got policy {spec.policy!r}, order {spec.order!r}"
+        )
+    heads, fh = params["a_self"].shape
+    z = x @ params["w"]
+    zh = z.reshape(-1, heads, fh)
+    s = jnp.einsum("vhf,hf->vh", zh, params["a_self"])
+    t = jnp.einsum("vhf,hf->vh", zh, params["a_nbr"])
+    if spec.use_pallas:
+        from ..kernels.gat_agg.ops import gat_agg
+
+        o = gat_agg(adj.indices, adj.weights, z, s, t, block_v=spec.band_size)
+    else:
+        from ..kernels.gat_agg.ref import gat_agg_ref
+
+        o = gat_agg_ref(adj.indices, adj.weights, z, s, t)
+    o = o[: adj.n_nodes].astype(x.dtype)
+    if last:
+        return o.reshape(-1, heads, fh).mean(axis=1) + params["b"]
+    return jax.nn.elu(o + params["b"])
 
 
-def init_layer(kind: str, rng: jax.Array, f_in: int, f_out: int):
+LAYER_FNS = {"gcn": gcn_layer, "sage": sage_layer, "gin": gin_layer,
+             "gat": gat_layer}
+
+#: the layer kinds whose last layer differs from the others (``last=``)
+LAST_AWARE = ("gat",)
+
+#: attention heads of a ``gat`` layer unless told otherwise: the K = 8 of
+#: the paper's citation-graph models
+DEFAULT_HEADS = 8
+
+
+def init_layer(kind: str, rng: jax.Array, f_in: int, f_out: int, *,
+               heads: int = DEFAULT_HEADS, concat: bool = True):
+    """One layer's parameters.  ``heads`` / ``concat`` matter only for
+    ``gat`` (the last layer of a model averages its heads)."""
     k1, k2, k3 = jax.random.split(rng, 3)
     scale = 1.0 / np.sqrt(f_in)
     if kind == "gcn":
@@ -367,4 +438,31 @@ def init_layer(kind: str, rng: jax.Array, f_in: int, f_out: int):
             "w2": jax.random.normal(k2, (f_out, f_out)) * (1.0 / np.sqrt(f_out)),
             "b2": jnp.zeros((f_out,)),
         }
+    if kind == "gat":
+        # F', one head's width: a concatenating layer's output is the heads
+        # side by side, an averaging one's is one head
+        if concat and (heads < 1 or f_out % heads):
+            raise ValueError(
+                f"a gat layer that concatenates {heads} heads needs an "
+                f"output width divisible by them, got {f_out}"
+            )
+        fh = f_out // heads if concat else f_out
+        ka, kb = jax.random.split(k3)
+        return {
+            "w": jax.random.normal(k1, (f_in, heads * fh)) * scale,
+            "a_self": jax.random.normal(ka, (heads, fh)) / np.sqrt(fh),
+            "a_nbr": jax.random.normal(kb, (heads, fh)) / np.sqrt(fh),
+            "b": jnp.zeros((f_out,)),
+        }
     raise KeyError(kind)
+
+
+def init_layers(kind: str, rng: jax.Array, dims, *,
+                heads: int = DEFAULT_HEADS):
+    """Parameters of a layer stack of ``(f_in, f_out)`` dims; only the
+    last layer averages its heads."""
+    keys = jax.random.split(rng, len(dims))
+    return [
+        init_layer(kind, k, fi, fo, heads=heads, concat=i < len(dims) - 1)
+        for i, (k, (fi, fo)) in enumerate(zip(keys, dims))
+    ]

@@ -27,7 +27,14 @@ import numpy as np
 from ..core.schedule import ModelSchedule
 from ..graphs.csr import CSRGraph
 from ..kernels.common import resolve_use_pallas
-from .layers import LAYER_FNS, EllAdjacency, init_layer, segment_readout
+from .layers import (
+    DEFAULT_HEADS,
+    LAST_AWARE,
+    LAYER_FNS,
+    EllAdjacency,
+    init_layers,
+    segment_readout,
+)
 
 #: set True after the first string-policy shim warning (reset by tests).
 _POLICY_SHIM_WARNED = False
@@ -49,7 +56,7 @@ def _warn_policy_shim() -> None:
 
 @dataclass(frozen=True)
 class GNNConfig:
-    kind: str = "gcn"  # gcn | sage | gin
+    kind: str = "gcn"  # gcn | sage | gin | gat
     f_in: int = 128
     hidden: int = 16  # Kipf-standard hidden width
     n_classes: int = 8
@@ -58,6 +65,7 @@ class GNNConfig:
     order: str = "AC"  # phase order
     band_size: int = 128
     use_pallas: bool | None = None  # Pallas kernels; None = on the TPU
+    heads: int = DEFAULT_HEADS  # attention heads of a gat layer
 
     @property
     def dims(self) -> list[tuple[int, int]]:
@@ -78,8 +86,7 @@ class GNNConfig:
 
 
 def init_gnn(cfg: GNNConfig, rng: jax.Array):
-    keys = jax.random.split(rng, cfg.n_layers)
-    return [init_layer(cfg.kind, k, fi, fo) for k, (fi, fo) in zip(keys, cfg.dims)]
+    return init_layers(cfg.kind, rng, cfg.dims, heads=cfg.heads)
 
 
 def forward_layers(kind: str, params, adj: EllAdjacency, x: jax.Array,
@@ -96,8 +103,9 @@ def forward_layers(kind: str, params, adj: EllAdjacency, x: jax.Array,
     """
     fn = LAYER_FNS[kind]
     h = x
-    for layer, spec in zip(params, specs):
-        h = fn(layer, adj, h, spec=spec, mesh=mesh)
+    for i, (layer, spec) in enumerate(zip(params, specs)):
+        kw = {"last": i == len(params) - 1} if kind in LAST_AWARE else {}
+        h = fn(layer, adj, h, spec=spec, mesh=mesh, **kw)
     if segment_ids is not None:
         if num_segments is None:
             raise ValueError("segment_ids needs num_segments")

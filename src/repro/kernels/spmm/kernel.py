@@ -23,6 +23,8 @@ so for finite features the result is bit for bit that of the padded walk.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -36,25 +38,37 @@ def occupied_width(weights: jax.Array) -> jax.Array:
     return jnp.max(jnp.where(weights != 0, slot, 0), axis=1, initial=0)
 
 
-def gather_rows(cnt_ref, idx_ref, wts_ref, x_ref, h_ref) -> None:
-    """h[b, :] = sum_{d < cnt[0, b]} wts[b, d] * x[idx[b, d], :] for every
-    row b of the block, accumulated in float32.  ``cnt_ref`` is the block's
-    ``(1, rows)`` slice of :func:`occupied_width`."""
-    rows = idx_ref.shape[0]
-    width = h_ref.shape[1]
+def walk_rows(cnt_ref, rows: int, init, slot, store) -> None:
+    """The occupied walk of a row block: for every row b < ``rows``,
+    ``carry = init(b)``, then ``carry = slot(b, d, carry)`` for each
+    d < cnt[0, b], then ``store(b, carry)``.  ``cnt_ref`` is the block's
+    ``(1, rows)`` slice of :func:`occupied_width`.  Every aggregation
+    kernel walks its slots through this one loop."""
 
     def row(b, carry):
-        def slot(d, acc):
-            nbr = x_ref[pl.ds(idx_ref[b, d], 1), :].astype(jnp.float32)
-            return acc + wts_ref[b, d] * nbr
-
         acc = jax.lax.fori_loop(
-            0, cnt_ref[0, b], slot, jnp.zeros((1, width), jnp.float32)
+            0, cnt_ref[0, b], functools.partial(slot, b), init(b)
         )
-        h_ref[pl.ds(b, 1), :] = acc.astype(h_ref.dtype)
+        store(b, acc)
         return carry
 
     jax.lax.fori_loop(0, rows, row, 0)
+
+
+def gather_rows(cnt_ref, idx_ref, wts_ref, x_ref, h_ref) -> None:
+    """h[b, :] = sum_{d < cnt[0, b]} wts[b, d] * x[idx[b, d], :] for every
+    row b of the block, accumulated in float32 (:func:`walk_rows`)."""
+    width = h_ref.shape[1]
+
+    def slot(b, d, acc):
+        nbr = x_ref[pl.ds(idx_ref[b, d], 1), :].astype(jnp.float32)
+        return acc + wts_ref[b, d] * nbr
+
+    def store(b, acc):
+        h_ref[pl.ds(b, 1), :] = acc.astype(h_ref.dtype)
+
+    walk_rows(cnt_ref, idx_ref.shape[0],
+              lambda b: jnp.zeros((1, width), jnp.float32), slot, store)
 
 
 def _kernel(cnt_ref, idx_ref, wts_ref, x_ref, o_ref):

@@ -63,10 +63,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ..api import Program, compile as _compile, compile_count, trace_count
-from ..core.cost_model import GNNLayerWorkload
+from ..api import (
+    Program,
+    compile as _compile,
+    compile_count,
+    layer_workloads,
+    trace_count,
+)
 from ..core.hw import AcceleratorConfig, DEFAULT_ACCEL, DEFAULT_LATENCY, LatencyModel
 from ..core.schedule import ModelSchedule
+from ..gnn.layers import DEFAULT_HEADS, init_layers
 from ..kernels.common import measure_wall, resolve_use_pallas
 from ..graphs.batching import (
     BucketPolicy,
@@ -202,6 +208,9 @@ class EngineStats:
     #: slots the aggregation kernels walk (they skip each row's padding)
     ell_slots: int = 0
     ell_slots_used: int = 0
+    #: Σ over bound micro-batches of real nonzeros x heads x layers: the
+    #: (edge, head) pairs a gat model scores (0 for other kinds)
+    attn_edge_heads: int = 0
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -336,6 +345,7 @@ class InferenceEngine:
         params=None,
         *,
         kind: str = "gcn",
+        heads: int = DEFAULT_HEADS,
         objective: str = "cycles",
         hw: AcceleratorConfig = DEFAULT_ACCEL,
         policy: BucketPolicy = BucketPolicy(),
@@ -360,6 +370,8 @@ class InferenceEngine:
             raise ValueError("engine needs at least one layer shape")
         self.params = params
         self.kind = kind
+        #: attention heads of every layer of a ``gat`` model
+        self.heads = int(heads)
         self.objective = objective
         self.hw = hw
         self.policy = policy
@@ -389,6 +401,8 @@ class InferenceEngine:
         #: (:func:`repro.graphs.partition.plan_partition`) instead of a
         #: typed rejection.  Off by default: the PR 6 rejection contract
         #: stays intact unless a deployment opts in.
+        if partition_oversized and kind == "gat":
+            raise ValueError("the partitioned lane has no gat layer")
         self.partition_oversized = partition_oversized
         self.max_partitions = max_partitions
         self.monitor = monitor if monitor is not None else StragglerMonitor()
@@ -435,6 +449,7 @@ class InferenceEngine:
         self._n_compiles = 0  # backend compiles taken by those executions
         self._ell_slots = 0  # padded-ELL slots of bound micro-batches
         self._ell_slots_used = 0  # their real nonzeros
+        self._attn_edge_heads = 0  # (edge, head) pairs scored (gat)
         self._n_searches = 0  # mapper searches actually run
         self._status_counts = {s: 0 for s in
                                (STATUS_OK, STATUS_REJECTED, STATUS_FAILED,
@@ -460,13 +475,7 @@ class InferenceEngine:
 
     def init(self, rng: jax.Array):
         """Initialize (and adopt) model parameters for the served dims."""
-        keys = jax.random.split(rng, len(self.dims))
-        from ..gnn.layers import init_layer
-
-        self.params = [
-            init_layer(self.kind, k, fi, fo)
-            for k, (fi, fo) in zip(keys, self.dims)
-        ]
+        self.params = init_layers(self.kind, rng, self.dims, heads=self.heads)
         return self.params
 
     # -- program cache -------------------------------------------------------
@@ -503,12 +512,20 @@ class InferenceEngine:
             use_pallas=tier.use_pallas,
             searched=tier.searched,
             hw=self.hw,
+            heads=self.heads if self.kind == "gat" else None,
         )
 
     def _default_schedule(self) -> ModelSchedule:
-        """The ladder's last rung: a fixed sp_opt/AC schedule that needs
-        no mapper search and no Pallas toolchain."""
+        """The ladder's last rung: a fixed sp_opt/AC schedule (seq/CA for
+        ``gat``, which runs no other order) that needs no mapper search
+        and no Pallas toolchain."""
+        if self.kind == "gat":
+            return ModelSchedule.from_policies("seq", "CA", self.dims)
         return ModelSchedule.from_policies("sp_opt", "AC", self.dims)
+
+    def _workloads(self, graph: CSRGraph) -> list:
+        return layer_workloads(graph.nnz, self.dims, kind=self.kind,
+                               heads=self.heads)
 
     def _program_for(self, batch: GraphBatch, tier: Tier) -> Program:
         """Compile — or load — the bucket's Program for one ladder tier.
@@ -544,12 +561,7 @@ class InferenceEngine:
                 if twin is not None:
                     prog = twin.degraded(use_pallas=False)
                 else:
-                    wls = [
-                        GNNLayerWorkload(
-                            batch.graph.nnz, fi, fo, name=f"layer{i}"
-                        )
-                        for i, (fi, fo) in enumerate(self.dims)
-                    ]
+                    wls = self._workloads(batch.graph)
                     if tier.searched:
                         sched = self.schedule or self._schedules.get(bucket)
                     else:
@@ -753,10 +765,7 @@ class InferenceEngine:
                 v_bucket, d_bucket = bucket
                 batch = self._synthetic_batch(v_bucket, d_bucket, slots)
                 incumbent = self._program_for(batch, tier)
-                wls = [
-                    GNNLayerWorkload(batch.graph.nnz, fi, fo, name=f"layer{i}")
-                    for i, (fi, fo) in enumerate(self.dims)
-                ]
+                wls = self._workloads(batch.graph)
                 x = jnp.zeros((batch.graph.n_nodes, self.f_in), jnp.float32)
                 seg = jnp.asarray(batch.segment_ids)
 
@@ -1542,7 +1551,10 @@ class InferenceEngine:
             bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
         self._ell_slots += bound.adj.indices.size
         # pad rows hold one zero-weight self-loop each, which no kernel walks
-        self._ell_slots_used += np.count_nonzero(batch.graph.values)
+        nnz = np.count_nonzero(batch.graph.values)
+        self._ell_slots_used += nnz
+        if self.kind == "gat":
+            self._attn_edge_heads += nnz * self.heads * len(self.dims)
         corrupt = None
         if self.injector is not None:
             corrupt = self.injector.on_run(
@@ -1641,4 +1653,5 @@ class InferenceEngine:
             n_compiles=self._n_compiles,
             ell_slots=self._ell_slots,
             ell_slots_used=self._ell_slots_used,
+            attn_edge_heads=self._attn_edge_heads,
         )

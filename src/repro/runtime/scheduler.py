@@ -117,6 +117,8 @@ class AsyncEngineStats:
     #: Σ over workers of EngineStats.ell_slots / ell_slots_used
     ell_slots: int = 0
     ell_slots_used: int = 0
+    #: Σ over workers of EngineStats.attn_edge_heads
+    attn_edge_heads: int = 0
     errors: dict = field(default_factory=dict)
     placement: dict = field(default_factory=dict)  # "VxD" -> [device labels]
     per_device: dict = field(default_factory=dict)  # label -> EngineStats dict
@@ -788,7 +790,7 @@ class AsyncEngine:
                 n_groups=self._n_groups,
             )
         per_device: dict[str, EngineStats] = {}
-        n_served = ell_slots = ell_slots_used = 0
+        n_served = ell_slots = ell_slots_used = attn_edge_heads = 0
         for w in self.workers:
             s = w.engine.stats()
             per_device[str(w.device)] = s
@@ -800,6 +802,7 @@ class AsyncEngine:
             n_served += s.n_ok + s.n_degraded
             ell_slots += s.ell_slots
             ell_slots_used += s.ell_slots_used
+            attn_edge_heads += s.attn_edge_heads
             for code, n in s.errors.items():
                 errors[code] = errors.get(code, 0) + n
         lat_ms = np.asarray(lat, dtype=np.float64) * 1e3
@@ -820,6 +823,7 @@ class AsyncEngine:
             **waits,
             ell_slots=ell_slots,
             ell_slots_used=ell_slots_used,
+            attn_edge_heads=attn_edge_heads,
             errors=errors,
             placement=self.placement(),
             per_device={k: v.as_dict() for k, v in per_device.items()},

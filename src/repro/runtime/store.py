@@ -81,6 +81,7 @@ def store_key(
     use_pallas: bool,
     searched: bool = True,
     hw=None,
+    heads: int | None = None,
 ) -> dict:
     """The canonical store key for one compiled serving artifact.
 
@@ -89,9 +90,10 @@ def store_key(
     shapes, so one artifact serves them all (``v_total`` distinguishes
     slot-count variants of the bucket — their executables differ).
     ``hw`` is an :class:`~repro.core.hw.AcceleratorConfig` (or ``None``
-    for "any").
+    for "any"); ``heads``, a ``gat`` model's attention heads, enters the
+    key only when given.
     """
-    return {
+    key = {
         "dims": [[int(fi), int(fo)] for fi, fo in dims],
         "bucket": [int(bucket[0]), int(bucket[1])],
         "v_total": int(v_total),
@@ -101,6 +103,9 @@ def store_key(
         "searched": bool(searched),
         "hw": None if hw is None else {k: v for k, v in sorted(asdict(hw).items())},
     }
+    if heads is not None:
+        key["heads"] = int(heads)
+    return key
 
 
 def key_digest(key: dict) -> str:

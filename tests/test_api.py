@@ -139,10 +139,15 @@ class TestCompile:
 def test_program_matches_string_policy_forward(graph, kind, order, policy):
     """The full policy x order x kind matrix: a Program built from the
     policy's default schedule reproduces the string-configured forward
-    pass (itself pinned to the dense reference in test_layers_numerics)."""
+    pass (itself pinned to the dense reference in test_layers_numerics).
+    A gat layer has no AC and no pp path: compiling one raises."""
     g, spec = graph
     cfg = GNNConfig(kind=kind, f_in=spec.n_features, hidden=8, n_classes=4,
                     policy=policy, order=order, band_size=32)
+    if kind == "gat" and (order == "AC" or policy == "pp"):
+        with pytest.raises(ValueError, match="CA and not PP"):
+            repro.compile(cfg, graph=g, schedule=cfg.default_schedule())
+        return
     prog = repro.compile(cfg, graph=g, schedule=cfg.default_schedule())
     params = init_gnn(cfg, jax.random.PRNGKey(7))
     x = _x(graph, spec.n_features)

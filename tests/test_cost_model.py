@@ -155,3 +155,50 @@ class TestTable3Buffering:
             mk("VsFsGt", "cmb", V=4, F=4),
         )
         assert pipelined_elements(df, self.wl) == 4 * 8
+
+
+class TestAttentionCost:
+    """An attention (GAT) layer: its two phases at the computed width H*F'
+    plus the score and softmax work, nnz*H."""
+
+    NNZ = np.array([3, 1, 5, 2, 4, 1, 2, 6], np.int64)
+
+    def wl(self, heads, concat=True, g_out=8):
+        return GNNLayerWorkload(self.NNZ, 16, g_out, heads=heads,
+                                concat=concat)
+
+    def test_width_and_fixed_weight_twin(self):
+        assert self.wl(8).width == 8
+        assert self.wl(8, concat=False, g_out=3).width == 24
+        twin = self.wl(8, concat=False, g_out=3).fixed_weight()
+        assert (twin.heads, twin.g_out, twin.f_in) == (0, 24, 16)
+        plain = GNNLayerWorkload(self.NNZ, 16, 8)
+        assert plain.fixed_weight() is plain and plain.width == 8
+
+    def test_hand_worked_edge_term(self):
+        from repro.core.cost_model import ATTN_OPS_PER_EDGE_HEAD, attention_cost
+
+        c = attention_cost(self.wl(4), HW)
+        e, v = int(self.NNZ.sum()), len(self.NNZ)
+        assert c.macs == 2 * v * 8 + ATTN_OPS_PER_EDGE_HEAD * e * 4
+        assert c.cycles == c.macs / HW.n_pes
+        assert c.gb_reads["att"] == e * 4
+        assert attention_cost(GNNLayerWorkload(self.NNZ, 16, 8), HW).cycles == 0
+
+    @pytest.mark.parametrize("concat", [True, False])
+    def test_edge_term_grows_with_heads(self, concat):
+        from repro.core import simulate
+        from repro.core.cost_model import attention_cost
+        from repro.core.schedule import default_dataflow
+
+        g_out = 8 if concat else 2
+        costs = [attention_cost(self.wl(h, concat, g_out), HW).cycles
+                 for h in (1, 2, 8)]
+        assert costs[0] < costs[1] < costs[2]
+        # the simulator charges it on top of the same two phases
+        df = default_dataflow("seq", "CA", band_size=4)
+        fixed = simulate(df, self.wl(8).fixed_weight(), HW)
+        one, eight = (simulate(df, self.wl(h), HW) for h in (1, 8))
+        assert fixed.cycles < one.cycles < eight.cycles
+        # each (edge, head) reads a score, each (node, head) writes one
+        assert eight.gb_accesses["att"] == 8 * (self.NNZ.sum() + len(self.NNZ))

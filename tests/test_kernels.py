@@ -12,6 +12,7 @@ from repro.kernels.fused_agg_cmb import fused_agg_cmb, fused_ref
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.gemm_dataflow import DATAFLOWS, gemm_ref
 from repro.kernels.gemm_dataflow.ops import gemm
+from repro.kernels.gat_agg import gat_agg, gat_agg_ref
 from repro.kernels.common import default_interpret, lane_block_f, row_block
 from repro.kernels.spmm import spmm, spmm_ref
 from repro.kernels.spmm.kernel import occupied_width
@@ -415,3 +416,101 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(out).reshape(2, sq, d), np.asarray(ref), rtol=3e-4, atol=3e-5
         )
+
+
+def gat_bucket(v, v_pad, d, seed=0):
+    """A GAT bucket: a hub row at the full ELL width ``d``, every fourth
+    real row with one slot, the others 1-3, rows ``v:v_pad`` empty."""
+    idx, wts = skewed_ell(v, v_pad, d, d, seed=seed)
+    one = jnp.arange(v_pad)[:, None] % 4 == 1
+    wts = jnp.where(one & (jnp.arange(d)[None, :] > 0), 0.0, wts)
+    return jnp.where(wts != 0, idx, 0), wts
+
+
+def gat_inputs(v, heads, fh, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    return tuple(rand(shape, rng=rng).astype(dtype)
+                 for shape in ((v, heads * fh), (v, heads), (v, heads)))
+
+
+class TestGatAgg:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("fh", [3, 8])
+    @pytest.mark.parametrize("heads", [1, 8])
+    def test_matches_oracle(self, heads, fh, dtype):
+        """Both walk the same inputs (a bfloat16 table is held exactly in
+        float32 by both), so only the softmax's summation order differs."""
+        v, v_pad = 50, 80
+        idx, wts = gat_bucket(v, v_pad, 24, seed=heads * fh)
+        z, s, t = gat_inputs(v, heads, fh, dtype, seed=fh)
+        out = gat_agg(idx, wts, z, s, t, block_v=16)
+        ref = gat_agg_ref(idx, wts, z, s, t)
+        assert out.shape == (v_pad, heads * fh) and out.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-6)
+        assert np.isfinite(np.asarray(out)).all()
+        # pad rows have no slot: 0, not NaN
+        np.testing.assert_array_equal(np.asarray(out[v:]), 0.0)
+
+    def test_one_slot_rows_copy_their_neighbour(self):
+        """A single slot has weight 1 whatever its score."""
+        idx, wts = gat_bucket(50, 64, 16, seed=3)
+        z, s, t = gat_inputs(50, 8, 8, jnp.float32, seed=4)
+        out = np.asarray(gat_agg(idx, wts, z, s, t, block_v=16))
+        ones = np.flatnonzero(np.asarray(occupied_width(wts))[:50] == 1)
+        assert len(ones) > 5
+        np.testing.assert_allclose(
+            out[ones], np.asarray(z)[np.asarray(idx)[ones, 0]], rtol=1e-6)
+
+    def test_hub_row_is_a_softmax(self):
+        """The hub's output per head lies in the convex hull of its
+        neighbours' rows: with z = 1 everywhere it is exactly 1."""
+        idx, wts = gat_bucket(50, 64, 40, seed=5)
+        _, s, t = gat_inputs(50, 8, 3, jnp.float32, seed=6)
+        out = gat_agg(idx, wts, jnp.ones((50, 24)), 10 * s, 10 * t,
+                      block_v=16)
+        np.testing.assert_allclose(np.asarray(out[:50]), 1.0, rtol=1e-5)
+
+    def test_occupied_walk_equals_padded_walk(self, monkeypatch):
+        """Skipped slots are masked, so stopping at the occupied width
+        gives the padded walk's result bit for bit."""
+        import repro.kernels.gat_agg.ops as gat_ops
+
+        idx, wts = gat_bucket(50, 80, 24, seed=7)
+        z, s, t = gat_inputs(50, 8, 8, jnp.float32, seed=8)
+        out = gat_agg(idx, wts, z, s, t, block_v=16)
+        monkeypatch.setattr(gat_ops, "occupied_width", full_width)
+        padded = jax.jit(gat_ops._gat_kernel, static_argnums=(5,))(
+            idx, wts, z, s, t, 16)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(padded))
+
+    def test_gradient_is_the_oracles(self):
+        """The custom VJP is the jnp oracle's: the gradients agree to the
+        forward passes' own difference."""
+        idx, wts = gat_bucket(40, 64, 16, seed=9)
+        z, s, t = gat_inputs(40, 8, 3, jnp.float32, seed=10)
+        g = rand((64, 24), rng=np.random.default_rng(11))
+
+        def loss(fn):
+            return lambda zz, ss, tt: (fn(idx, wts, zz, ss, tt) * g).sum()
+
+        got = jax.grad(loss(lambda *a: gat_agg(*a, block_v=16)),
+                       argnums=(0, 1, 2))(z, s, t)
+        want = jax.grad(loss(gat_agg_ref), argnums=(0, 1, 2))(z, s, t)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+    def test_table_over_vmem_is_refused(self):
+        """The resident table must fit the kernel's VMEM limit."""
+        import repro.kernels.gat_agg.ops as gat_ops
+
+        v = gat_ops.MAX_SCOPED_VMEM // (4 * 128) + 8
+        assert gat_ops.vmem_need(v, 64, 128) > gat_ops.MAX_SCOPED_VMEM
+        idx = jax.ShapeDtypeStruct((v, 8), jnp.int32)
+        f = jax.ShapeDtypeStruct((v, 64), jnp.float32)
+        sc = jax.ShapeDtypeStruct((v, 8), jnp.float32)
+        with pytest.raises(ValueError, match="resident in VMEM"):
+            jax.eval_shape(lambda i, w, z, s, t: gat_agg(i, w, z, s, t),
+                           idx, jax.ShapeDtypeStruct((v, 8), jnp.float32),
+                           f, sc, sc)

@@ -1,6 +1,7 @@
 """Numerics matrix for `repro.gnn.layers`: every policy x order x kind
 combination must match a dense reference built from `aggregate_full` on a
-random CSR graph, including when ``v_pad % band_size != 0``."""
+random CSR graph, including when ``v_pad % band_size != 0``.  A gat layer
+runs CA only and has no pp path: the other combinations raise."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +14,7 @@ from repro.graphs import from_edges
 V = 157  # prime: v_pad % band_size != 0 for every power-of-two band
 F_IN, F_OUT = 20, 12
 BAND = 32  # 157 % 32 != 0
+HEADS = 4  # a gat layer's 12 outputs are 4 heads of 3
 
 
 @pytest.fixture(scope="module")
@@ -53,6 +55,23 @@ def dense_layer_reference(kind, params, adj, x):
             + params["b1"]
         )
         return jax.nn.relu(h @ params["w2"] + params["b2"])
+    if kind == "gat":
+        # dense masked softmax over A + I (the nonzero ELL slots)
+        heads, fh = params["a_self"].shape
+        z = (xs @ params["w"]).reshape(-1, heads, fh)
+        s = jnp.einsum("vhf,hf->vh", z, params["a_self"])
+        t = jnp.einsum("vhf,hf->vh", z, params["a_nbr"])
+        mask = np.zeros((adj.n_nodes, adj.n_nodes), bool)
+        idx, w = np.asarray(adj.indices), np.asarray(adj.weights)
+        rows = np.repeat(np.arange(idx.shape[0]), idx.shape[1])
+        live = w.ravel() != 0
+        mask[rows[live], idx.ravel()[live]] = True
+        e = s[:, None, :] + t[None, :, :]
+        e = jnp.where(e > 0, e, 0.2 * e)
+        e = jnp.where(mask[:, :, None], e, -jnp.inf)
+        a = jax.nn.softmax(e, axis=1)
+        o = jnp.einsum("ijh,jhf->ihf", a, z).reshape(adj.n_nodes, -1)
+        return jax.nn.elu(o + params["b"])
     raise KeyError(kind)
 
 
@@ -61,7 +80,12 @@ def dense_layer_reference(kind, params, adj, x):
 @pytest.mark.parametrize("policy", POLICIES)
 def test_policy_order_kind_matrix(kind, order, policy, adj, x):
     """`pp` with mesh=None exercises its documented sp_generic fallback."""
-    params = init_layer(kind, jax.random.PRNGKey(42), F_IN, F_OUT)
+    params = init_layer(kind, jax.random.PRNGKey(42), F_IN, F_OUT, heads=HEADS)
+    if kind == "gat" and (order == "AC" or policy == "pp"):
+        with pytest.raises(ValueError, match="CA"):
+            LAYER_FNS[kind](params, adj, x, policy=policy, order=order,
+                            band_size=BAND)
+        return
     ref = dense_layer_reference(kind, params, adj, x)
     out = LAYER_FNS[kind](
         params, adj, x, policy=policy, order=order, band_size=BAND

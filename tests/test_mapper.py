@@ -6,12 +6,15 @@ import pytest
 from repro.core import (
     AcceleratorConfig,
     GNNLayerWorkload,
+    InterPhase,
+    PhaseOrder,
     TileStats,
     named_dataflow,
     named_skeleton,
     optimize_tiles,
     optimize_tiles_topk,
     search_dataflows,
+    search_model,
     simulate,
     simulate_batch,
 )
@@ -216,3 +219,69 @@ class TestTopKAndPruning:
         b = search_dataflows(wl, HW)
         assert [r.skeleton for r in a] == [r.skeleton for r in b]
         assert a[0].stats.cycles == b[0].stats.cycles
+
+
+class TestAttentionLayers:
+    """A gat layer needs z = X W before any score: the mapper offers it CA
+    dataflows only, and a PP or AC schedule is refused at compile time."""
+
+    @pytest.fixture(scope="class")
+    def workloads(self):
+        nnz = np.random.default_rng(3).integers(1, 20, 512)
+        return [GNNLayerWorkload(nnz, 50, 64, "l0", heads=8),
+                GNNLayerWorkload(nnz, 64, 3, "l1", heads=8, concat=False)]
+
+    def test_search_offers_ca_only(self, workloads):
+        from repro.core.mapper import ATTENTION_NAMES, search_dataflows
+
+        for wl in workloads:
+            res = search_dataflows(wl, objective="cycles", top_k=2)
+            assert res and {r.skeleton for r in res} <= set(ATTENTION_NAMES)
+            assert all(r.dataflow.order == PhaseOrder.CA
+                       and r.dataflow.inter != InterPhase.PP for r in res)
+        sched = search_model(workloads)
+        for layer in sched.layers:
+            spec = layer.lower(use_pallas=True)
+            assert spec.order == "CA" and spec.policy != "pp"
+            assert spec.band_size >= 8  # the kernel's row block
+
+    def test_batch_marks_ac_and_pp_illegal(self, workloads):
+        from repro.core import simulate_batch
+        from repro.core.schedule import default_dataflow
+
+        dfs = [default_dataflow(p, o, band_size=16)
+               for p in ("seq", "sp_generic", "sp_opt", "pp")
+               for o in ("AC", "CA")]
+        legal = simulate_batch(dfs, workloads[0]).legal
+        want = [o == "CA" and p != "pp"
+                for p in ("seq", "sp_generic", "sp_opt", "pp")
+                for o in ("AC", "CA")]
+        assert legal.tolist() == want
+        # the legal ones match the scalar oracle
+        batch = simulate_batch(dfs, workloads[0])
+        for k, df in enumerate(dfs):
+            if want[k]:
+                st = simulate(df, workloads[0])
+                assert batch.cycles[k] == pytest.approx(st.cycles, rel=1e-6)
+                assert batch.energy_pj[k] == pytest.approx(st.energy_pj,
+                                                           rel=1e-6)
+
+    @pytest.mark.parametrize("policy,order", [("pp", "CA"), ("seq", "AC"),
+                                              ("sp_opt", "AC")])
+    def test_compile_refuses_pp_and_ac(self, workloads, policy, order):
+        import repro
+        from repro.core.schedule import ModelSchedule
+
+        sched = ModelSchedule.from_policies(
+            policy, order, [(w.f_in, w.g_out) for w in workloads])
+        with pytest.raises(ValueError, match="CA and not PP"):
+            repro.compile(workloads, schedule=sched, kind="gat")
+
+    def test_compile_checks_heads_against_kind(self, workloads):
+        import repro
+
+        with pytest.raises(ValueError, match="only gat"):
+            repro.compile(workloads, kind="gcn")
+        plain = [GNNLayerWorkload(w.nnz, w.f_in, w.g_out) for w in workloads]
+        with pytest.raises(ValueError, match="nonzero heads"):
+            repro.compile(plain, kind="gat")

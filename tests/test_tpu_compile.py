@@ -6,8 +6,9 @@ the widths the serving path uses: the citeseer bucket of the paper's
 2-layer GCN (4096 rows, ELL width 64, 3703 -> 16 -> 6), the whole-citeseer
 bucket whose hub sets ELL width 128, an IMDB-BINARY bucket (64 slots of 32
 nodes, ELL width 32, 136 -> 16 -> 2), a batched mutag bucket (64 slots of
-32 nodes, 28 features), and the mapper's narrow ``block_f`` of 8; and a
-training step's gradient through each kernel.  Every case carries the
+32 nodes, 28 features), the mapper's narrow ``block_f`` of 8, and the
+GAT aggregation at the whole-PubMed bucket (32,768 rows, ELL width 256);
+and a training step's gradient through each kernel.  Every case carries the
 kernels' per-row occupied widths (an SMEM block walked by a dynamic trip
 count), so a form of them Mosaic refuses fails here.
 The compiler refuses what interpret mode accepts
@@ -22,12 +23,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import repro.kernels.fused_agg_cmb.ops as fused_ops
+import repro.kernels.gat_agg.ops as gat_ops
 import repro.kernels.spmm.ops as spmm_ops
 
 CITESEER = dict(rows=4096, d=64)
 CITESEER_FULL = dict(rows=4096, d=128)
 IMDB = dict(rows=32 * 64, d=32)
 MUTAG = dict(rows=32 * 64, d=8)
+#: the whole-PubMed bucket of the published GAT: 19,717 nodes in 32,768
+#: rows, its 171-neighbour hub setting ELL width 256
+PUBMED = dict(rows=32768, d=256)
 
 
 @pytest.fixture(scope="module")
@@ -70,6 +75,7 @@ def for_chip(monkeypatch, one_chip, no_compile_cache):
     backend is the CPU)."""
     monkeypatch.setattr(spmm_ops, "default_interpret", lambda: False)
     monkeypatch.setattr(fused_ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(gat_ops, "default_interpret", lambda: False)
 
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -145,5 +151,37 @@ def test_grad_step_compiles_for_v5e(for_chip, kernel):
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         x, w, idx, wts
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "heads,fh,block_v",
+    [(8, 8, 32), (8, 3, 8), (8, 8, 128)],
+    ids=["pubmed-layer1", "pubmed-layer2", "pubmed-block128"],
+)
+def test_gat_agg_compiles_for_v5e(for_chip, heads, fh, block_v):
+    """The GAT aggregation at the whole-PubMed bucket: layer 1's [z | t]
+    is 64 + 8 = 72 columns of 19,717 nodes, resident in VMEM."""
+    idx, wts = _ell(for_chip, **PUBMED)
+    z = for_chip(19717, heads * fh)
+    s, t = for_chip(19717, heads), for_chip(19717, heads)
+    compiled = jax.jit(
+        lambda i, a, z, s, t: gat_ops.gat_agg(i, a, z, s, t, block_v=block_v)
+    ).lower(idx, wts, z, s, t).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gat_grad_step_compiles_for_v5e(for_chip):
+    """A training step's gradient through the GAT kernel (the backward is
+    the jnp oracle's VJP)."""
+    idx, wts = _ell(for_chip, **MUTAG)
+    z, s, t = for_chip(MUTAG["rows"], 64), *(for_chip(MUTAG["rows"], 8),) * 2
+
+    def loss(z, s, t, i, a):
+        return (gat_ops.gat_agg(i, a, z, s, t, block_v=32) ** 2).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        z, s, t, idx, wts
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
