@@ -66,7 +66,7 @@ class EllAdjacency:
                 f"pad_to={pad_to} is narrower than the graph's max degree "
                 f"{g.max_degree}; neighbor lists would be truncated"
             )
-        idx, wts, _ = g.to_ell(block_rows, pad_to=pad_to)
+        idx, wts = g.ell_arrays(block_rows, pad_to=pad_to)
         return cls(jnp.asarray(idx), jnp.asarray(wts), g.n_nodes)
 
     @classmethod

@@ -3,9 +3,10 @@
 The paper assumes CSR adjacency (Sec. 2.1, Fig. 3b).  On TPU, truly random
 CSR walks do not vectorize, so the JAX execution path uses a padded
 row-block layout (ELL): rows grouped into blocks, neighbor lists padded to
-the block's max degree.  The padding waste *is* the paper's lockstep /
-evil-row cost, so the same structure feeds both the simulator (exact nnz
-array) and the JAX/Pallas kernels (padded indices + mask).
+one width, the max degree or a bucket's.  The padding waste *is* the
+paper's lockstep / evil-row cost, so the same structure feeds both the
+simulator (exact nnz array) and the JAX/Pallas kernels (padded indices +
+mask).
 """
 from __future__ import annotations
 
@@ -54,22 +55,35 @@ class CSRGraph:
 
     def to_ell(self, block_rows: int = 1, pad_to: int | None = None):
         """Padded neighbor lists: returns (indices, weights, mask) of shape
-        (V_pad, D) where D = max degree over each `block_rows` row block,
-        rounded up to the global max (single buffer).  Padded slots point at
-        row 0 with weight 0, so gather+weighted-sum stays correct."""
+        (V_pad, D), V_pad = V rounded up to `block_rows` and D = `pad_to`,
+        else the global max degree (at least 1).  A row longer than D keeps
+        its first D entries.  Padded slots, and rows past V, point at row 0
+        with weight 0, so gather+weighted-sum stays correct."""
+        idx, wts = self.ell_arrays(block_rows, pad_to)
+        d = idx.shape[1]
+        msk = np.zeros(idx.shape, dtype=bool)
+        msk[: self.n_nodes] = np.arange(d) < np.minimum(self.nnz, d)[:, None]
+        return idx, wts, msk
+
+    def ell_arrays(self, block_rows: int = 1, pad_to: int | None = None):
+        """(indices, weights) of :meth:`to_ell`, without the mask.  One
+        scatter for every kept nonzero: entry j of row r lands at flat slot
+        r * D + (j - row_ptr[r])."""
         v = self.n_nodes
         d = pad_to or max(self.max_degree, 1)
         v_pad = -(-v // block_rows) * block_rows
+        ptr = self.row_ptr[: v + 1].astype(np.int64)
+        entry = np.arange(ptr[0], ptr[-1])
+        row = np.repeat(np.arange(v), np.diff(ptr))
+        slot = entry - ptr[row]
+        keep = slot < d
+        flat = (row * d + slot)[keep]
+        entry = entry[keep]
         idx = np.zeros((v_pad, d), dtype=np.int32)
         wts = np.zeros((v_pad, d), dtype=np.float32)
-        msk = np.zeros((v_pad, d), dtype=bool)
-        for r in range(v):
-            s, e = self.row_ptr[r], self.row_ptr[r + 1]
-            k = min(e - s, d)
-            idx[r, :k] = self.col_idx[s : s + k]
-            wts[r, :k] = self.values[s : s + k]
-            msk[r, :k] = True
-        return idx, wts, msk
+        idx.reshape(-1)[flat] = self.col_idx[entry]
+        wts.reshape(-1)[flat] = self.values[entry]
+        return idx, wts
 
 
 def from_edges(

@@ -21,7 +21,13 @@ from repro.gnn import (
     make_node_classification_task,
     multiphase_matmul,
 )
-from repro.graphs import TABLE4, from_edges, load_dataset
+from repro.graphs import (
+    TABLE4,
+    BucketPolicy,
+    assemble,
+    from_edges,
+    load_dataset,
+)
 
 
 @pytest.fixture(scope="module")
@@ -353,3 +359,92 @@ class TestGat:
         plain.init(jax.random.PRNGKey(3))
         plain.submit([Request(graph=csrs[0], x=xs[0], rid=0)])
         assert plain.stats().attn_edge_heads == 0
+
+
+def ell_by_rows(g, block_rows=1, pad_to=None):
+    """The padded ELL built one row at a time: the oracle for
+    ``CSRGraph.to_ell``'s single scatter."""
+    v = g.n_nodes
+    d = pad_to or max(g.max_degree, 1)
+    v_pad = -(-v // block_rows) * block_rows
+    idx = np.zeros((v_pad, d), dtype=np.int32)
+    wts = np.zeros((v_pad, d), dtype=np.float32)
+    msk = np.zeros((v_pad, d), dtype=bool)
+    for r in range(v):
+        s, e = g.row_ptr[r], g.row_ptr[r + 1]
+        k = min(e - s, d)
+        idx[r, :k] = g.col_idx[s : s + k]
+        wts[r, :k] = g.values[s : s + k]
+        msk[r, :k] = True
+    return idx, wts, msk
+
+
+def _ring(n):
+    src = np.arange(n)
+    return from_edges(n, np.concatenate([src, (src + 1) % n]),
+                      np.concatenate([(src + 1) % n, src]))
+
+
+def _ell_case(name):
+    """(graph, block_rows, pad_to) for one named ELL build."""
+    rng = np.random.default_rng(11)
+    if name == "random":
+        return from_edges(60, rng.integers(0, 60, 200),
+                          rng.integers(0, 60, 200)), 1, None
+    if name == "powerlaw_hub_full_width":
+        g = from_edges(*citation_like(300, 1))
+        assert (g.nnz == g.max_degree).sum() >= 1  # the hub fills D
+        return g, 1, None
+    if name == "assembled_batch_with_pad_rows":
+        batch = assemble([_ring(20), _ring(25), _ring(30)], BucketPolicy())
+        assert batch.n_pad > 0
+        return batch.graph, 1, batch.d_bucket
+    if name == "block_rows_not_dividing_v":
+        return from_edges(50, rng.integers(0, 50, 120),
+                          rng.integers(0, 50, 120)), 8, None
+    if name == "pad_to_wider":
+        return _ring(40), 4, 16
+    if name == "pad_to_truncates":
+        g = from_edges(*citation_like(120, 2))
+        assert g.max_degree > 4
+        return g, 1, 4
+    if name == "zero_degree_rows":
+        g = from_edges(30, np.array([0, 3, 3, 7]), np.array([3, 0, 7, 3]),
+                       add_self_loops=False)
+        assert (g.nnz == 0).sum() > 20
+        return g, 8, None
+    if name == "zero_nodes":
+        return from_edges(0, np.array([], int), np.array([], int)), 8, None
+    raise KeyError(name)
+
+
+ELL_CASES = ["random", "powerlaw_hub_full_width",
+             "assembled_batch_with_pad_rows", "block_rows_not_dividing_v",
+             "pad_to_wider", "pad_to_truncates", "zero_degree_rows",
+             "zero_nodes"]
+
+
+class TestToEll:
+    @pytest.mark.parametrize("case", ELL_CASES)
+    def test_matches_row_by_row_build(self, case):
+        g, block_rows, pad_to = _ell_case(case)
+        got = g.to_ell(block_rows, pad_to=pad_to)
+        want = ell_by_rows(g, block_rows, pad_to=pad_to)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert np.array_equal(a, b)
+
+    @pytest.mark.parametrize("case", ELL_CASES)
+    def test_serving_arrays_are_to_ells(self, case):
+        """``ell_arrays`` (what ``EllAdjacency.from_csr`` binds) is
+        ``to_ell`` without its mask."""
+        g, block_rows, pad_to = _ell_case(case)
+        idx, wts, _ = g.to_ell(block_rows, pad_to=pad_to)
+        got = g.ell_arrays(block_rows, pad_to=pad_to)
+        for a, b in zip(got, (idx, wts)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert np.array_equal(a, b)
+        if pad_to is None or pad_to >= g.max_degree:
+            adj = EllAdjacency.from_csr(g, block_rows, pad_to=pad_to)
+            assert np.array_equal(np.asarray(adj.indices), idx)
+            assert np.array_equal(np.asarray(adj.weights), wts)
