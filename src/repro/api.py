@@ -261,15 +261,13 @@ class Program:
         self,
         n_nodes: int,
         mesh,
-        donate: bool,
         readout: str | None,
         num_segments: int | None,
     ):
         """The shape-keyed jitted forward.  jit's own cache handles the
         per-(array shape, dtype) keying; this dict keys the static closure
-        knobs.  ``donate`` donates the feature buffer (serving streams
-        never reuse it), a no-op on backends without donation."""
-        key = (n_nodes, mesh, donate, readout, num_segments)
+        knobs."""
+        key = (n_nodes, mesh, readout, num_segments)
         exe = self._exec_cache.get(key)
         if exe is None:
             kind, specs = self.kind, self.specs
@@ -284,7 +282,7 @@ class Program:
                     readout=readout or "mean",
                 )
 
-            exe = jax.jit(fwd, donate_argnums=(3,) if donate else ())
+            exe = jax.jit(fwd)
             self._exec_cache[key] = exe
         return exe
 
@@ -297,7 +295,6 @@ class Program:
         segment_ids=None,
         num_segments: int | None = None,
         readout: str | None = None,
-        donate: bool = False,
     ) -> jax.Array:
         """Forward pass under the compiled schedule.
 
@@ -310,10 +307,12 @@ class Program:
 
         Executables are cached per input shape: the second call on a
         same-shape input (including a same-shape :meth:`bind`) performs
-        zero re-tracing (see :func:`repro.api.trace_count`).
+        zero re-tracing (see :func:`repro.api.trace_count`).  The executable
+        leaves ``x`` intact, so the caller may run it again (a serving
+        retry, a lower ladder tier, a repeated measurement).
         """
         exe, args = self._call(
-            params, x, mesh, segment_ids, num_segments, readout, donate
+            params, x, mesh, segment_ids, num_segments, readout
         )
         return exe(*args)
 
@@ -326,17 +325,16 @@ class Program:
         segment_ids=None,
         num_segments: int | None = None,
         readout: str | None = None,
-        donate: bool = False,
     ) -> "jax.stages.Lowered":
         """The executable :meth:`run` would call with these arguments, as
         lowered by ``jax.jit`` (``.compile().as_text()`` shows which
         kernels it runs)."""
         exe, args = self._call(
-            params, x, mesh, segment_ids, num_segments, readout, donate
+            params, x, mesh, segment_ids, num_segments, readout
         )
         return exe.lower(*args)
 
-    def _call(self, params, x, mesh, segment_ids, num_segments, readout, donate):
+    def _call(self, params, x, mesh, segment_ids, num_segments, readout):
         """The shape-keyed executable and its arguments for one run."""
         adj = self._require_adj()
         if len(params) != self.n_layers:
@@ -354,7 +352,6 @@ class Program:
         exe = self._executable(
             adj.n_nodes,
             mesh,
-            donate,
             (readout or "mean") if batched else None,
             num_segments,
         )
@@ -372,7 +369,6 @@ class Program:
         segment_ids=None,
         num_segments: int | None = None,
         readout: str | None = None,
-        donate: bool = False,
     ) -> int:
         """Warm the executable cache for one input shape, off the request
         path: runs :meth:`run` on a zeros feature array of the bound
@@ -395,7 +391,6 @@ class Program:
             segment_ids=segment_ids,
             num_segments=num_segments,
             readout=readout,
-            donate=donate,
         )
         jax.block_until_ready(out)
         return _TRACE_COUNT - before

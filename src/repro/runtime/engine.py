@@ -30,10 +30,15 @@ batches 64/32 graphs per inference, Sec. 5.1.2).  The
    :meth:`InferenceEngine.precompile` replays the recorded
    :class:`~repro.graphs.batching.TrafficProfile` at startup so even the
    XLA traces happen off the request path (zero-cold-start serving).
-5. **Execute with fault isolation**: each micro-batch walks the
+   Serving, warm-up and re-ranking call a Program with one call shape
+   (:meth:`InferenceEngine._batch_kwargs`), so the executable a warm-up
+   primes is the one a request asks for.
+5. **Execute with fault isolation**: each micro-batch — and each
+   oversized request served through a partition plan — walks the
    degradation ladder (:func:`repro.runtime.resilience.default_ladder` —
    searched+Pallas -> searched+jnp -> default schedule) with bounded
-   retries per tier; non-finite outputs raise instead of returning
+   retries per tier, in one loop (:meth:`InferenceEngine._execute_ladder`);
+   non-finite outputs raise instead of returning
    silently.  A multi-graph batch that faults at every tier is re-run
    request by request (**solo-retry quarantine**), so one poisoned request
    fails alone with a typed status while its neighbors still return
@@ -53,10 +58,9 @@ import itertools
 import json
 import logging
 import time
-import warnings
 from collections import OrderedDict
 from dataclasses import asdict, dataclass, field, replace as dc_replace
-from typing import Sequence
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -160,6 +164,29 @@ class Result:
     def ok(self) -> bool:
         """True when ``output`` is a served answer (ok or degraded)."""
         return self.status in (STATUS_OK, STATUS_DEGRADED)
+
+    @classmethod
+    def from_error(
+        cls,
+        rid: int,
+        err: ServingError,
+        latency_s: float,
+        bucket: tuple[int, int] | None = None,
+        **extras,
+    ) -> "Result":
+        """The typed non-``ok`` result of one request that ``err`` ended;
+        ``extras`` are further fields (retries, partition telemetry)."""
+        return cls(
+            rid=rid,
+            output=None,
+            bucket=bucket,
+            latency_s=latency_s,
+            status=err.status,
+            error=str(err),
+            error_type=err.code,
+            retry_after_s=getattr(err, "retry_after_s", None),
+            **extras,
+        )
 
 
 @dataclass
@@ -360,7 +387,6 @@ class InferenceEngine:
         check_numerics: bool = True,
         monitor: StragglerMonitor | None = None,
         store: ProgramStore | None = None,
-        donate: bool = True,
         device_label: str | None = None,
         partition_oversized: bool = False,
         max_partitions: int = 256,
@@ -388,12 +414,6 @@ class InferenceEngine:
         self.max_inflight_graphs = max_inflight_graphs
         self.injector = fault_injector
         self.check_numerics = check_numerics
-        #: donate feature buffers to the executables.  The async front-end
-        #: turns this off: it stages features onto the target device ahead
-        #: of dispatch, and a donated pre-staged buffer could not survive a
-        #: ladder retry.  The flag is part of the executable cache key, so
-        #: an engine must pick one mode and keep it (precompile honors it).
-        self.donate = donate
         #: stamped on every Result this engine produces (the async
         #: front-end labels each per-device engine with its jax device).
         self.device_label = device_label
@@ -589,6 +609,23 @@ class InferenceEngine:
         ``(v_bucket, v_total, d_bucket)`` shape it was compiled for."""
         return [(key[4], prog) for key, prog in self.cache.items()]
 
+    def _batch_kwargs(self, batch: GraphBatch) -> dict:
+        """The batching arguments of :meth:`Program.run
+        <repro.api.Program.run>` / :meth:`~repro.api.Program.prime` for one
+        micro-batch: none for node logits (``readout=None``), else the
+        per-graph readout over the padded slot count, not ``n_graphs`` —
+        the executable's shape then depends only on the bucket, so tail
+        batches at any fill level reuse it (pad segments are sliced off
+        after the run).  Every run and prime of the engine goes through
+        this, so a primed executable is the one a request asks for."""
+        if self.readout is None:
+            return {}
+        return dict(
+            segment_ids=jnp.asarray(batch.segment_ids),
+            num_segments=batch.slots,
+            readout=self.readout,
+        )
+
     # -- ahead-of-time warmup ------------------------------------------------
     def _synthetic_batch(
         self, v_bucket: int, d_bucket: int, slots: int
@@ -659,35 +696,20 @@ class InferenceEngine:
         hits0 = self.store.hits if self.store is not None else 0
         searches0 = self._n_searches
         misses0 = self.cache.misses
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message="Some donated buffers")
-            for (v_bucket, d_bucket), slots in shapes:
-                batch = self._synthetic_batch(v_bucket, d_bucket, slots)
-                self._buckets_seen.add((v_bucket, d_bucket))
-                prog = self._program_for(batch, tier)
-                bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
-                compiles0 = compile_count()
-                t_run = time.perf_counter()
-                # prime with this engine's donate flag: the jit-executable
-                # cache keys on it, so a donate=False (async) engine must
-                # warm donate=False executables or the first real request
-                # would re-trace
-                if self.readout is None:
-                    n_new = bound.prime(self.params, donate=self.donate)
-                else:
-                    n_new = bound.prime(
-                        self.params,
-                        segment_ids=jnp.asarray(batch.segment_ids),
-                        num_segments=batch.slots,
-                        readout=self.readout,
-                        donate=self.donate,
-                    )
-                n_compiles = compile_count() - compiles0
-                self._n_compiles += n_compiles
-                if n_new or n_compiles:
-                    self._trace_s += time.perf_counter() - t_run
-                rep.n_shapes += 1
-                rep.n_traces += n_new
+        for (v_bucket, d_bucket), slots in shapes:
+            batch = self._synthetic_batch(v_bucket, d_bucket, slots)
+            self._buckets_seen.add((v_bucket, d_bucket))
+            prog = self._program_for(batch, tier)
+            bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
+            compiles0 = compile_count()
+            t_run = time.perf_counter()
+            n_new = bound.prime(self.params, **self._batch_kwargs(batch))
+            n_compiles = compile_count() - compiles0
+            self._n_compiles += n_compiles
+            if n_new or n_compiles:
+                self._trace_s += time.perf_counter() - t_run
+            rep.n_shapes += 1
+            rep.n_traces += n_new
         rep.n_store_hits = (
             (self.store.hits - hits0) if self.store is not None else 0
         )
@@ -723,8 +745,8 @@ class InferenceEngine:
         2. compile each candidate with a *pinned* schedule (no search)
            and measure it on a synthetic batch of the bucket's hottest
            slot count via :func:`~repro.kernels.common.measure_wall`
-           (``donate=False`` so the measurement buffer survives repeat
-           runs); every measurement lands in the profile's observation
+           (the serving call shape, :meth:`_batch_kwargs`); every
+           measurement lands in the profile's observation
            ledger (:meth:`TrafficProfile.record_wall
            <repro.graphs.batching.TrafficProfile.record_wall>`);
         3. when the best candidate beats the incumbent by more than
@@ -732,7 +754,7 @@ class InferenceEngine:
            the bucket: pin the winner in the per-bucket schedule map,
            overwrite the memory-cache entry *and* the store artifact for
            every recorded slot variant, and re-prime the serving
-           executables with this engine's own ``donate`` mode — so the
+           executables in the same call shape — so the
            next real request of the bucket re-traces nothing
            (``repro.trace_count()`` delta of 0 on the request path).
         """
@@ -758,91 +780,70 @@ class InferenceEngine:
             hot_slots.setdefault(bucket, slots)
             variants.setdefault(bucket, []).append(slots)
 
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message="Some donated buffers")
-            for bucket, slots in hot_slots.items():
-                rep.n_buckets += 1
-                v_bucket, d_bucket = bucket
-                batch = self._synthetic_batch(v_bucket, d_bucket, slots)
-                incumbent = self._program_for(batch, tier)
-                wls = self._workloads(batch.graph)
-                x = jnp.zeros((batch.graph.n_nodes, self.f_in), jnp.float32)
-                seg = jnp.asarray(batch.segment_ids)
+        for bucket, slots in hot_slots.items():
+            rep.n_buckets += 1
+            v_bucket, d_bucket = bucket
+            batch = self._synthetic_batch(v_bucket, d_bucket, slots)
+            incumbent = self._program_for(batch, tier)
+            wls = self._workloads(batch.graph)
+            x = jnp.zeros((batch.graph.n_nodes, self.f_in), jnp.float32)
+            kw = self._batch_kwargs(batch)
 
-                def measure(prog: Program) -> float:
-                    bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
-
-                    def run():
-                        if self.readout is None:
-                            return bound.run(self.params, x, donate=False)
-                        return bound.run(
-                            self.params,
-                            x,
-                            segment_ids=seg,
-                            num_segments=batch.slots,
-                            readout=self.readout,
-                            donate=False,
-                        )
-
-                    wall = measure_wall(run, warmup=warmup, iters=iters)
-                    self.profile.record_wall(
-                        bucket, batch.slots, prog.schedule_digest, wall
-                    )
-                    return wall
-
-                walls: dict[str, tuple[float, Program]] = {
-                    incumbent.schedule_digest: (measure(incumbent), incumbent)
-                }
-                for cand in search_model_topk(
-                    wls, hw=self.hw, objective=self.objective, top_k=top_k
-                ):
-                    dig = cand.digest()
-                    if dig in walls:
-                        continue
-                    prog = _compile(
-                        wls,
-                        hw=self.hw,
-                        objective=self.objective,
-                        schedule=cand,
-                        kind=self.kind,
-                        use_pallas=tier.use_pallas,
-                    )
-                    rep.n_candidates += 1
-                    walls[dig] = (measure(prog), prog)
-                best_dig, (best_wall, best_prog) = min(
-                    walls.items(), key=lambda kv: kv[1][0]
+            def measure(prog: Program) -> float:
+                bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
+                wall = measure_wall(
+                    lambda: bound.run(self.params, x, **kw),
+                    warmup=warmup, iters=iters,
                 )
-                inc_wall = walls[incumbent.schedule_digest][0]
-                if (
-                    best_dig == incumbent.schedule_digest
-                    or best_wall >= inc_wall * (1.0 - min_improvement)
-                ):
+                self.profile.record_wall(
+                    bucket, batch.slots, prog.schedule_digest, wall
+                )
+                return wall
+
+            walls: dict[str, tuple[float, Program]] = {
+                incumbent.schedule_digest: (measure(incumbent), incumbent)
+            }
+            for cand in search_model_topk(
+                wls, hw=self.hw, objective=self.objective, top_k=top_k
+            ):
+                dig = cand.digest()
+                if dig in walls:
                     continue
-                rep.n_swapped += 1
-                self._schedules[bucket] = best_prog.schedule
-                rep.swaps[f"{v_bucket}x{d_bucket}"] = {
-                    "from": incumbent.schedule_digest,
-                    "to": best_dig,
-                    "incumbent_wall_s": inc_wall,
-                    "winner_wall_s": best_wall,
-                    "improvement": 1.0 - best_wall / inc_wall,
-                }
-                for sv in variants[bucket]:
-                    vb = self._synthetic_batch(v_bucket, d_bucket, sv)
-                    self.cache.put(self._cache_key(vb, tier), best_prog)
-                    if self.store is not None:
-                        self.store.put(self._store_key(vb, tier), best_prog)
-                    bound = best_prog.bind(vb.graph, pad_degree=vb.d_bucket)
-                    if self.readout is None:
-                        bound.prime(self.params, donate=self.donate)
-                    else:
-                        bound.prime(
-                            self.params,
-                            segment_ids=jnp.asarray(vb.segment_ids),
-                            num_segments=vb.slots,
-                            readout=self.readout,
-                            donate=self.donate,
-                        )
+                prog = _compile(
+                    wls,
+                    hw=self.hw,
+                    objective=self.objective,
+                    schedule=cand,
+                    kind=self.kind,
+                    use_pallas=tier.use_pallas,
+                )
+                rep.n_candidates += 1
+                walls[dig] = (measure(prog), prog)
+            best_dig, (best_wall, best_prog) = min(
+                walls.items(), key=lambda kv: kv[1][0]
+            )
+            inc_wall = walls[incumbent.schedule_digest][0]
+            if (
+                best_dig == incumbent.schedule_digest
+                or best_wall >= inc_wall * (1.0 - min_improvement)
+            ):
+                continue
+            rep.n_swapped += 1
+            self._schedules[bucket] = best_prog.schedule
+            rep.swaps[f"{v_bucket}x{d_bucket}"] = {
+                "from": incumbent.schedule_digest,
+                "to": best_dig,
+                "incumbent_wall_s": inc_wall,
+                "winner_wall_s": best_wall,
+                "improvement": 1.0 - best_wall / inc_wall,
+            }
+            for sv in variants[bucket]:
+                vb = self._synthetic_batch(v_bucket, d_bucket, sv)
+                self.cache.put(self._cache_key(vb, tier), best_prog)
+                if self.store is not None:
+                    self.store.put(self._store_key(vb, tier), best_prog)
+                bound = best_prog.bind(vb.graph, pad_degree=vb.d_bucket)
+                bound.prime(self.params, **self._batch_kwargs(vb))
         if self.store is not None:
             self.store.save_profile(self.profile)
         rep.n_traces = trace_count() - traces0
@@ -900,14 +901,16 @@ class InferenceEngine:
         return None
 
     # -- bookkeeping ---------------------------------------------------------
-    def _record(self, results: list, pos: int, res: Result,
-                err: ServingError | None = None) -> None:
+    def _record(self, results: list, pos: int, res: Result) -> None:
+        """Store one request's Result and count its status and, for a
+        typed failure (:meth:`Result.from_error`), its taxonomy code."""
         if self.device_label is not None and res.device is None:
             res = dc_replace(res, device=self.device_label)
         results[pos] = res
         self._status_counts[res.status] += 1
-        if err is not None:
-            self._errors[err.code] = self._errors.get(err.code, 0) + 1
+        if res.error_type is not None:
+            code = res.error_type
+            self._errors[code] = self._errors.get(code, 0) + 1
 
     # -- serving -------------------------------------------------------------
     def submit(self, requests: Sequence[Request]) -> list[Result]:
@@ -947,7 +950,8 @@ class InferenceEngine:
             if err is None:
                 admitted.append(pos)
                 inflight_units += 1
-            elif self.partition_oversized and isinstance(err, OversizedGraph):
+                continue
+            if self.partition_oversized and isinstance(err, OversizedGraph):
                 try:
                     units = self._plan_for(req.graph).n_partitions
                 except ValueError:
@@ -976,69 +980,29 @@ class InferenceEngine:
                     partitioned.append(pos)
                     inflight_units += units
                     continue
-                self._record(
-                    results,
-                    pos,
-                    Result(
-                        rid=req.rid,
-                        output=None,
-                        bucket=None,
-                        latency_s=time.perf_counter() - t_submit,
-                        status=err.status,
-                        error=str(err),
-                        error_type=err.code,
-                        retry_after_s=err.retry_after_s,
-                    ),
-                    err,
-                )
-            else:
-                self._record(
-                    results,
-                    pos,
-                    Result(
-                        rid=req.rid,
-                        output=None,
-                        bucket=None,
-                        latency_s=time.perf_counter() - t_submit,
-                        status=err.status,
-                        error=str(err),
-                        error_type=err.code,
-                        retry_after_s=getattr(err, "retry_after_s", None),
-                    ),
-                    err,
-                )
+            self._record(results, pos, Result.from_error(
+                req.rid, err, time.perf_counter() - t_submit
+            ))
 
         if admitted:
             routed = bucketize(
                 [requests[i].graph for i in admitted], self.policy
             )
-            with warnings.catch_warnings():
-                # buffer donation is advisory; CPU warns it off
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers"
-                )
-                for bucket_key, local_idxs in routed.items():
-                    self._buckets_seen.add(bucket_key)
-                    self.profile.record_request(bucket_key, len(local_idxs))
-                    idxs = [admitted[j] for j in local_idxs]
-                    for chunk in _chunks(idxs, self.policy.max_graphs):
-                        live = self._enforce_deadlines(
-                            requests, chunk, bucket_key, t_arrival, results
-                        )
-                        if live:
-                            self._serve_batch(
-                                requests, live, bucket_key, results,
-                                t_arrival=t_arrival,
-                            )
-        if partitioned:
-            with warnings.catch_warnings():
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers"
-                )
-                for pos in partitioned:
-                    self._serve_partitioned(
-                        requests, pos, results, t_arrival[pos]
+            for bucket_key, local_idxs in routed.items():
+                self._buckets_seen.add(bucket_key)
+                self.profile.record_request(bucket_key, len(local_idxs))
+                idxs = [admitted[j] for j in local_idxs]
+                for chunk in _chunks(idxs, self.policy.max_graphs):
+                    live = self._enforce_deadlines(
+                        requests, chunk, bucket_key, t_arrival, results
                     )
+                    if live:
+                        self._serve_batch(
+                            requests, live, bucket_key, results,
+                            t_arrival=t_arrival,
+                        )
+        for pos in partitioned:
+            self._serve_partitioned(requests, pos, results, t_arrival[pos])
         self._wall_s += time.perf_counter() - t_submit
         if self.store is not None:
             self.store.save_profile(self.profile)
@@ -1053,7 +1017,9 @@ class InferenceEngine:
         batch_id: int | None = None,
     ) -> list[Result]:
         """Serve one *pre-admitted*, same-bucket group of requests — the
-        async front-end's batching-window flush path.
+        async front-end's batching-window flush path — as one micro-batch.
+        A window flushes as soon as it holds ``policy.max_graphs``
+        requests, so a group is never larger than one batch.
 
         The caller owns admission (the PR 6 contract puts it **before**
         queueing, so nothing malformed, oversized or shed ever reaches a
@@ -1061,7 +1027,7 @@ class InferenceEngine:
         enforced here, at the window, against each request's own
         ``t_arrival`` (its enqueue time, ``time.perf_counter()`` clock) —
         as are the reported latencies, so a request's latency is its
-        queue wait plus its micro-batch, never the whole flush chunk.
+        queue wait plus its micro-batch.
 
         ``pre`` is an optionally pre-assembled ``(GraphBatch, features)``
         pair whose features the front-end already staged onto this
@@ -1090,20 +1056,16 @@ class InferenceEngine:
         self.profile.record_request(bucket_key, len(requests))
         results: list[Result | None] = [None] * len(requests)
         idxs = list(range(len(requests)))
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message="Some donated buffers")
-            for chunk in _chunks(idxs, self.policy.max_graphs):
-                live = self._enforce_deadlines(
-                    requests, chunk, bucket_key, t_arrival, results
-                )
-                if live:
-                    self._serve_batch(
-                        requests, live, bucket_key, results,
-                        t_arrival=t_arrival,
-                        pre=pre if live == idxs else None,
-                        batch_id=batch_id,
-                    )
-                    batch_id = None  # a further chunk is a batch of its own
+        live = self._enforce_deadlines(
+            requests, idxs, bucket_key, t_arrival, results
+        )
+        if live:
+            self._serve_batch(
+                requests, live, bucket_key, results,
+                t_arrival=t_arrival,
+                pre=pre if live == idxs else None,
+                batch_id=batch_id,
+            )
         self._wall_s += time.perf_counter() - t0
         return results  # type: ignore[return-value]
 
@@ -1124,11 +1086,9 @@ class InferenceEngine:
             )
         t0 = time.perf_counter()
         results: list[Result | None] = [None]
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message="Some donated buffers")
-            self._serve_partitioned(
-                [req], 0, results, t_arrival if t_arrival is not None else t0
-            )
+        self._serve_partitioned(
+            [req], 0, results, t_arrival if t_arrival is not None else t0
+        )
         self._n_requests += 1
         self._wall_s += time.perf_counter() - t0
         if self.store is not None:
@@ -1172,35 +1132,23 @@ class InferenceEngine:
                 f"request {req.rid}: deadline {dl:.3f}s expired "
                 f"({t0 - t_arr:.3f}s elapsed) before partitioned execution"
             )
-            self._record(
-                results, pos,
-                Result(
-                    rid=req.rid, output=None, bucket=None,
-                    latency_s=t0 - t_arr, status=STATUS_FAILED,
-                    error=str(err), error_type=err.code,
-                ),
-                err,
-            )
+            self._record(results, pos,
+                         Result.from_error(req.rid, err, t0 - t_arr))
             return
         bucket_key = self.policy.bucket_of(req.graph)
         try:
             plan = self._plan_for(req.graph)
         except ValueError as e:
             err = OversizedGraph(f"request {req.rid}: {e}")
-            self._record(
-                results, pos,
-                Result(
-                    rid=req.rid, output=None, bucket=bucket_key,
-                    latency_s=time.perf_counter() - t_arr,
-                    status=err.status, error=str(err), error_type=err.code,
-                ),
-                err,
-            )
+            self._record(results, pos, Result.from_error(
+                req.rid, err, time.perf_counter() - t_arr, bucket_key
+            ))
             return
 
-        out, n_parts, tier_idx, n_retries, err = (
-            self._execute_partitioned_ladder(req, plan)
+        done, tier_idx, n_retries, err = self._execute_ladder(
+            lambda tier: self._execute_partitioned(req, plan, tier)
         )
+        out, n_parts = done if err is None else (None, plan.n_partitions)
         wall = time.perf_counter() - t0
         lat = time.perf_counter() - t_arr
         self._latencies.append(lat)
@@ -1210,17 +1158,10 @@ class InferenceEngine:
             self._partition_plans.get(plan.kind, 0) + 1
         )
         if err is not None:
-            self._record(
-                results, pos,
-                Result(
-                    rid=req.rid, output=None, bucket=bucket_key,
-                    latency_s=lat, status=err.status, error=str(err),
-                    error_type=err.code, n_retries=n_retries,
-                    n_partitions=n_parts, partition_wall_s=wall,
-                    plan=plan.kind,
-                ),
-                err,
-            )
+            self._record(results, pos, Result.from_error(
+                req.rid, err, lat, bucket_key, n_retries=n_retries,
+                n_partitions=n_parts, partition_wall_s=wall, plan=plan.kind,
+            ))
             return
         if tier_idx > 0:
             self._n_downgrades += 1
@@ -1233,30 +1174,6 @@ class InferenceEngine:
                 tier=tier.name, n_retries=n_retries,
                 n_partitions=n_parts, partition_wall_s=wall, plan=plan.kind,
             ),
-        )
-
-    def _execute_partitioned_ladder(self, req: Request, plan):
-        """Walk the degradation ladder around the whole partition loop
-        (the PR 6 retry/downgrade contract, per oversized request)."""
-        last: BaseException | None = None
-        n_retries = 0
-        n_parts = plan.n_partitions
-        for tier_idx, tier in enumerate(self.ladder):
-            for attempt in range(self.retry.max_attempts):
-                try:
-                    out, n_parts = self._execute_partitioned(req, plan, tier)
-                    return out, n_parts, tier_idx, n_retries, None
-                except Exception as e:  # noqa: BLE001 — isolate any fault
-                    last = e
-                    if attempt < self.retry.max_retries:
-                        n_retries += 1
-                        self._n_retries += 1
-                        self.retry.sleep_for(attempt)
-            self._log_step_down(tier_idx, last)
-        assert last is not None
-        return (
-            None, n_parts, len(self.ladder) - 1, n_retries,
-            as_serving_error(last),
         )
 
     def _execute_partitioned(self, req: Request, plan, tier: Tier):
@@ -1300,9 +1217,7 @@ class InferenceEngine:
                 x_in = jnp.asarray(batch.batch_features([x_full[part.nodes]]))
                 # enqueue without blocking: the device crunches this
                 # partition while the host gathers the next one's halo
-                pending.append(
-                    (bound.run(self.params, x_in, donate=False), part.n_own)
-                )
+                pending.append((bound.run(self.params, x_in), part.n_own))
             slices = [
                 np.asarray(jax.block_until_ready(o))[:n_own]
                 for o, n_own in pending
@@ -1361,20 +1276,9 @@ class InferenceEngine:
                     f"request {requests[i].rid}: deadline {dl:.3f}s expired "
                     f"({elapsed:.3f}s elapsed) before batch assembly"
                 )
-                self._record(
-                    results,
-                    i,
-                    Result(
-                        rid=requests[i].rid,
-                        output=None,
-                        bucket=bucket_key,
-                        latency_s=elapsed,
-                        status=STATUS_FAILED,
-                        error=str(err),
-                        error_type=err.code,
-                    ),
-                    err,
-                )
+                self._record(results, i, Result.from_error(
+                    requests[i].rid, err, elapsed, bucket_key
+                ))
             else:
                 live.append(i)
         return live
@@ -1415,7 +1319,9 @@ class InferenceEngine:
         self._batch_seq[bucket_key] = batch_index + 1
 
         outs, tier_idx, n_retries, err = self._execute_ladder(
-            batch, x_in, rids, bucket_key, batch_index, batch_id
+            lambda tier: self._attempt(
+                batch, x_in, rids, bucket_key, batch_index, batch_id, tier
+            )
         )
         dt = time.perf_counter() - t0
         t_done = time.perf_counter()
@@ -1439,21 +1345,9 @@ class InferenceEngine:
                 return
             lat = t_done - t_arrival[idxs[0]]
             self._latencies.append(lat)
-            self._record(
-                results,
-                idxs[0],
-                Result(
-                    rid=rids[0],
-                    output=None,
-                    bucket=bucket_key,
-                    latency_s=lat,
-                    status=err.status,
-                    error=str(err),
-                    error_type=err.code,
-                    n_retries=n_retries,
-                ),
-                err,
-            )
+            self._record(results, idxs[0], Result.from_error(
+                rids[0], err, lat, bucket_key, n_retries=n_retries
+            ))
             return
 
         tier = self.ladder[tier_idx]
@@ -1477,36 +1371,22 @@ class InferenceEngine:
                 ),
             )
 
-    def _execute_ladder(
-        self,
-        batch: GraphBatch,
-        x_in,
-        rids: list[int],
-        bucket_key: tuple[int, int],
-        batch_index: int,
-        batch_id: int,
-    ):
+    def _execute_ladder(self, run_tier: Callable[[Tier], object]):
         """Walk the degradation ladder with bounded retries per tier.
 
-        ``x_in`` is the assembled feature block: a host ``np.ndarray`` on
-        the sync path, or a ``jax.Array`` the front-end already staged on
-        this engine's device (never donated — retries and other ladder
-        tiers must be able to reuse it).
-
-        Returns ``(outputs, tier_index, n_retries, error)`` — ``error`` is
-        ``None`` on success, the (taxonomy-wrapped) last failure when every
-        tier is exhausted.
+        ``run_tier(tier)`` is one attempt on one tier: :meth:`_attempt`
+        for a micro-batch, :meth:`_execute_partitioned` for an oversized
+        request.  Returns ``(output, tier_index, n_retries, error)`` —
+        ``output`` is what ``run_tier`` returned and ``error`` is ``None``
+        on success; when every tier is exhausted ``output`` is ``None`` and
+        ``error`` the (taxonomy-wrapped) last failure.
         """
         last: BaseException | None = None
         n_retries = 0
         for tier_idx, tier in enumerate(self.ladder):
             for attempt in range(self.retry.max_attempts):
                 try:
-                    outs = self._attempt(
-                        batch, x_in, rids, bucket_key, batch_index, batch_id,
-                        tier,
-                    )
-                    return outs, tier_idx, n_retries, None
+                    return run_tier(tier), tier_idx, n_retries, None
                 except Exception as e:  # noqa: BLE001 — isolate any fault
                     last = e
                     if attempt < self.retry.max_retries:
@@ -1545,7 +1425,12 @@ class InferenceEngine:
         batch_id: int,
         tier: Tier,
     ) -> list[np.ndarray]:
-        """One execution attempt on one tier (the unit of retry)."""
+        """One execution attempt on one tier (the unit of retry).
+
+        ``x_in`` is the assembled feature block: a host ``np.ndarray`` on
+        the sync path, or a ``jax.Array`` the front-end already staged on
+        this engine's device.  The run leaves it intact, so retries and
+        lower ladder tiers reuse it."""
         prog = self._program_for(batch, tier)
         with TraceAnnotation("repro.bind", batch=batch_id):
             bound = prog.bind(batch.graph, pad_degree=batch.d_bucket)
@@ -1560,29 +1445,11 @@ class InferenceEngine:
             corrupt = self.injector.on_run(
                 bucket_key, batch_index, rids, tier.name
             )
-        staged = isinstance(x_in, jax.Array)
-        x = x_in if staged else jnp.asarray(x_in)
-        # a staged buffer must survive retries and lower ladder tiers;
-        # donating it would leave the next attempt with a dead buffer
-        donate = self.donate and not staged
+        x = jnp.asarray(x_in)  # a staged jax.Array passes through as is
         traces_before, compiles_before = trace_count(), compile_count()
         t_run = time.perf_counter()
         with TraceAnnotation("repro.execute", batch=batch_id):
-            if self.readout is None:
-                out = bound.run(self.params, x, donate=donate)
-            else:
-                # readout over the padded slot count, not n_graphs: the
-                # executable shape then depends only on the bucket, so
-                # tail batches at any fill level reuse it (pad segments
-                # are sliced off below)
-                out = bound.run(
-                    self.params,
-                    x,
-                    segment_ids=jnp.asarray(batch.segment_ids),
-                    num_segments=batch.slots,
-                    readout=self.readout,
-                    donate=donate,
-                )
+            out = bound.run(self.params, x, **self._batch_kwargs(batch))
             arr = np.asarray(jax.block_until_ready(out))
         wall = time.perf_counter() - t_run
         n_compiles = compile_count() - compiles_before

@@ -332,9 +332,10 @@ class AsyncEngine:
     or its ``window_ms`` deadline expires — so under load p99 tracks the
     window, not the batch that happened to contain the request.
 
-    Every per-device engine is constructed with ``donate=False`` (staged
-    feature buffers must survive ladder retries) and the shared ``store``;
-    everything else mirrors the sync :class:`InferenceEngine` kwargs.
+    Every per-device engine is an :class:`InferenceEngine` built from the
+    same kwargs (the shared ``store`` included) and labelled with its
+    device; ``max_inflight_graphs`` is dropped, because admission is the
+    front-end's.
     """
 
     def __init__(
@@ -354,18 +355,13 @@ class AsyncEngine:
             raise ValueError("no devices to place buckets on")
         self.window_s = float(window_ms) / 1e3
         self.max_queue_graphs = max_queue_graphs
-        engine_kwargs.pop("donate", None)
         # admission is the front-end's job — per-engine shedding would
         # double-count a stream that is already capped at the queue
         engine_kwargs.pop("max_inflight_graphs", None)
         self.workers: list[_DeviceWorker] = []
         for i, dev in enumerate(self.devices):
             eng = InferenceEngine(
-                dims,
-                params,
-                donate=False,
-                device_label=str(dev),
-                **engine_kwargs,
+                dims, params, device_label=str(dev), **engine_kwargs
             )
             self.workers.append(_DeviceWorker(i, dev, eng, self))
         e0 = self.workers[0].engine
@@ -525,16 +521,7 @@ class AsyncEngine:
                 self.placer.outstanding[part_widx] += 1
             elif err is not None:
                 lat = time.perf_counter() - t_arrival
-                res = Result(
-                    rid=req.rid,
-                    output=None,
-                    bucket=None,
-                    latency_s=lat,
-                    status=err.status,
-                    error=str(err),
-                    error_type=err.code,
-                    retry_after_s=getattr(err, "retry_after_s", None),
-                )
+                res = Result.from_error(req.rid, err, lat)
                 self._fe_status[err.status] += 1
                 self._fe_errors[err.code] = self._fe_errors.get(err.code, 0) + 1
                 self._fe_latencies.append(lat)
@@ -609,28 +596,24 @@ class AsyncEngine:
         host->device transfer overlaps the device's current batch."""
         worker = self.workers[widx]
         for win in wins:
-            pre = None
-            if len(win.requests) <= self.policy.max_graphs:
-                try:
-                    with TraceAnnotation("repro.assemble", batch=win.batch_id):
-                        batch = assemble(
-                            [r.graph for r in win.requests], self.policy
-                        )
-                        x_np = batch.batch_features(
-                            [r.x for r in win.requests]
-                        )
-                    # place (don't commit) the feature block on the target
-                    # device: committed-ness is part of the jit dispatch
-                    # key, and precompile's prime warms the uncommitted
-                    # variant — a committed device_put here would pay a
-                    # fresh XLA compile per shape despite the warm cache
-                    with (
-                        TraceAnnotation("repro.stage", batch=win.batch_id),
-                        jax.default_device(worker.device),
-                    ):
-                        pre = (batch, jax.numpy.asarray(x_np))
-                except Exception:
-                    pre = None  # fall back to in-engine assembly
+            try:
+                with TraceAnnotation("repro.assemble", batch=win.batch_id):
+                    batch = assemble(
+                        [r.graph for r in win.requests], self.policy
+                    )
+                    x_np = batch.batch_features([r.x for r in win.requests])
+                # place (don't commit) the feature block on the target
+                # device: committed-ness is part of the jit dispatch key,
+                # and precompile's prime warms the uncommitted variant — a
+                # committed device_put here would pay a fresh XLA compile
+                # per shape despite the warm cache
+                with (
+                    TraceAnnotation("repro.stage", batch=win.batch_id),
+                    jax.default_device(worker.device),
+                ):
+                    pre = (batch, jax.numpy.asarray(x_np))
+            except Exception:
+                pre = None  # fall back to in-engine assembly
             done: "Future[list[Result]]" = Future()
             done.add_done_callback(
                 self._make_resolver(widx, win.futures, len(win.requests))

@@ -323,15 +323,33 @@ class TestDegradation:
         stats = eng.stats()
         assert stats.n_downgrades == 1 and stats.n_degraded == 1
 
-    def test_pallas_step_down_logged_once_with_cause(self, params, caplog):
+    @pytest.mark.parametrize("lane", ["batched", "partitioned"])
+    def test_pallas_step_down_logged_once_with_cause(
+        self, params, caplog, lane
+    ):
         """A Pallas tier the backend refuses must not degrade in silence:
-        the first step down is logged with its cause, every one counted."""
-        eng = make_engine(params, use_pallas=True)
+        the first step down is logged with its cause, every one counted.
+        An oversized request served through a partition plan walks the
+        same ladder as a micro-batch."""
+        if lane == "batched":
+            eng = make_engine(params, use_pallas=True)
+            sizes = (16, 32)
+        else:
+            eng = make_engine(
+                params, use_pallas=True, partition_oversized=True,
+                policy=BucketPolicy(min_nodes=16, min_degree=4, max_nodes=64),
+            )
+            sizes = (200, 200)
         with kill_pallas(), caplog.at_level("WARNING", logger="repro.runtime"):
-            a = eng.submit([make_request(16, seed=0, rid=0)])
-            b = eng.submit([make_request(32, seed=1, rid=1)])
+            a = eng.submit([make_request(sizes[0], seed=0, rid=0)])
+            b = eng.submit([make_request(sizes[1], seed=1, rid=1)])
         assert [r.tier for r in a + b] == ["jnp+searched"] * 2
+        assert [r.status for r in a + b] == ["degraded"] * 2
+        if lane == "partitioned":
+            assert all(r.plan == "row_stream" and r.n_partitions > 1
+                       for r in a + b)
         assert eng.stats().n_downgrades == 2
+        assert eng.stats().n_degraded == 2
         logged = [r for r in caplog.records if "stepping down" in r.getMessage()]
         assert len(logged) == 1
         assert "pallas+searched" in logged[0].getMessage()

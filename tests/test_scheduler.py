@@ -261,28 +261,40 @@ def test_async_window_and_inbox_waits_are_counted(params):
     assert 0.0 <= st.inbox_wait_s - st0.inbox_wait_s < r.latency_s
 
 
-def test_async_precompile_warms_assigned_buckets(tmp_path, params):
+@pytest.mark.parametrize("readout", [None, "mean"])
+def test_async_precompile_warms_assigned_buckets(tmp_path, params, readout):
     """precompile() on a revived engine loads from the shared store and
-    leaves the first real request trace-free (PR 7 contract)."""
+    leaves the first real request trace-free (zero cold start) — in both
+    call shapes a served batch takes: node logits and per-graph readout."""
     from repro.api import trace_count
     from repro.runtime import ProgramStore
 
     reqs = _stream(6)
     with AsyncEngine(
-        DIMS, params, window_ms=5.0, store=ProgramStore(tmp_path)
+        DIMS, params, window_ms=5.0, store=ProgramStore(tmp_path),
+        readout=readout,
     ) as a:
         assert all(r.ok for r in a.submit(reqs))
     # revive: fresh engine on the same store
     with AsyncEngine(
-        DIMS, params, window_ms=5.0, store=ProgramStore(tmp_path)
+        DIMS, params, window_ms=5.0, store=ProgramStore(tmp_path),
+        readout=readout,
     ) as b:
         rep = b.precompile()
         assert rep.n_shapes > 0
         assert rep.n_searches == 0  # every program came from the store
         before = trace_count()
+        compiles = sum(w.engine.stats().n_compiles for w in b.workers)
         res = b.submit(reqs)
         assert all(r.ok for r in res)
         assert trace_count() == before  # warm path: zero new traces
+        # ... and no backend compile either: the primed executable is the
+        # very one the served batch asks for
+        assert sum(w.engine.stats().n_compiles for w in b.workers) == compiles
+    for r, q in zip(res, reqs):
+        want = (q.graph.n_nodes, DIMS[-1][1]) if readout is None else (
+            DIMS[-1][1],)
+        assert np.shape(r.output) == want
 
 
 # ---------------------------------------------------------------------------
