@@ -176,22 +176,38 @@ class GraphBatch:
         return np.concatenate([[0], np.cumsum(self.sizes)[:-1]]).astype(np.int64)
 
     def batch_features(self, xs: Sequence[np.ndarray]) -> np.ndarray:
-        """Stack per-graph node features into the batched (v_total, F)
-        array (zeros on pad rows)."""
+        """Stack per-graph node features into a fresh float32 (v_total, F)
+        block, F taken from ``xs[0]``.
+
+        Each byte is written once: member ``i``'s rows are cast into
+        their row slice (at ``offsets[i]``) of an uninitialised block,
+        and only the pad rows after the last member are zeroed, so the
+        block holds no uninitialised memory.  The result shares no
+        memory with any input, and each call returns a new block.
+        """
         if len(xs) != self.n_graphs:
             raise ValueError(
                 f"batch holds {self.n_graphs} graphs but got {len(xs)} "
                 f"feature arrays"
             )
+        f = xs[0].shape[1]
         for x, n in zip(xs, self.sizes):
             if x.shape[0] != n:
                 raise ValueError(
                     f"feature array has {x.shape[0]} rows for a "
                     f"{n}-node graph"
                 )
-        f = xs[0].shape[1]
-        out = np.zeros((self.v_total, f), dtype=np.float32)
-        out[: int(self.sizes.sum())] = np.concatenate(xs, axis=0)
+            if x.shape[1:] != (f,):
+                raise ValueError(
+                    f"feature array has shape {x.shape}; every member "
+                    f"must be (n, {f}) like the first"
+                )
+        out = np.empty((self.v_total, f), dtype=np.float32)
+        o = 0
+        for x in xs:
+            out[o : o + len(x)] = x
+            o += len(x)
+        out[o:] = 0
         return out
 
     def split_nodes(self, out: np.ndarray) -> list[np.ndarray]:

@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+from repro.graphs import batching
 from repro.graphs import (
     BucketPolicy,
     CSRGraph,
@@ -160,3 +161,86 @@ class TestAssemble:
     def test_empty_batch_rejected(self):
         with pytest.raises(ValueError, match="at least one"):
             assemble([], self.POL)
+
+
+def reference_batch_features(batch, xs):
+    """The formula `GraphBatch.batch_features` replaced: a zeroed block
+    and one copy of the concatenated members."""
+    out = np.zeros((batch.v_total, xs[0].shape[1]), dtype=np.float32)
+    out[: int(batch.sizes.sum())] = np.concatenate(xs, axis=0)
+    return out
+
+
+def member_features(sizes, dtypes, f=5, seed=0):
+    rng = np.random.default_rng(seed)
+    xs = [rng.standard_normal((n, f)).astype(dt) for n, dt in zip(sizes, dtypes)]
+    xs[0][0, :2] = [-0.0, np.nan]  # sign of zero and NaN survive the cast
+    return xs
+
+
+class TestBatchFeatures:
+    POL = BucketPolicy(min_nodes=8, min_degree=4, max_graphs=8)
+
+    @pytest.mark.parametrize(
+        "sizes, n_pad",
+        [((5,), 3), ((8,), 0), ((5, 7, 6), 14), ((8, 8), 0)],
+        ids=["one-padded", "one-exact", "several-padded", "several-exact"],
+    )
+    @pytest.mark.parametrize("dtypes", ["float32", "float64", "mixed"])
+    def test_matches_zeroed_concatenate_bit_for_bit(self, sizes, n_pad, dtypes):
+        batch = assemble([line_graph(n) for n in sizes], self.POL)
+        assert batch.n_pad == n_pad
+        kinds = {"mixed": [np.float64, np.float32]}.get(dtypes, [dtypes])
+        xs = member_features(sizes, (kinds * len(sizes))[: len(sizes)])
+        got = batch.batch_features(xs)
+        want = reference_batch_features(batch, xs)
+        assert got.dtype == np.float32 and got.shape == want.shape
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+    def test_pad_rows_zeroed_over_uninitialised_memory(self, monkeypatch):
+        batch = assemble([line_graph(5), line_graph(7), line_graph(6)], self.POL)
+        xs = member_features((5, 7, 6), [np.float32] * 3)
+        calls = []
+
+        def nan_empty(shape, dtype=float, **kw):
+            calls.append(shape)
+            return np.full(shape, np.nan, dtype=dtype, **kw)
+
+        class NanNumpy:
+            empty = staticmethod(nan_empty)
+
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+        monkeypatch.setattr(batching, "np", NanNumpy())
+        got = batch.batch_features(xs)
+        assert calls == [(batch.v_total, 5)]
+        assert batch.n_pad > 0
+        np.testing.assert_array_equal(got[18:].view(np.uint32), 0)
+        np.testing.assert_array_equal(
+            got.view(np.uint32), reference_batch_features(batch, xs).view(np.uint32)
+        )
+
+    @pytest.mark.parametrize("sizes", [(8,), (5, 7, 6)])
+    def test_result_aliases_no_input(self, sizes):
+        batch = assemble([line_graph(n) for n in sizes], self.POL)
+        xs = member_features(sizes, [np.float32] * len(sizes))
+        before = [x.copy() for x in xs]
+        got = batch.batch_features(xs)
+        assert not any(np.shares_memory(got, x) for x in xs)
+        got[:] = 7.0
+        for x, b in zip(xs, before):
+            np.testing.assert_array_equal(x.view(np.uint32), b.view(np.uint32))
+
+    def test_each_call_returns_a_new_block(self):
+        batch = assemble([line_graph(5)], self.POL)
+        xs = member_features((5,), [np.float32])
+        a, b = batch.batch_features(xs), batch.batch_features(xs)
+        assert a is not b and not np.shares_memory(a, b)
+
+    @pytest.mark.parametrize("bad", [(7, 4), (7, 6), (7,), (7, 5, 1)])
+    def test_member_width_must_match_first(self, bad):
+        batch = assemble([line_graph(5), line_graph(7)], self.POL)
+        xs = [np.zeros((5, 5), np.float32), np.zeros(bad, np.float32)]
+        with pytest.raises(ValueError, match=r"must be \(n, 5\)"):
+            batch.batch_features(xs)
